@@ -3,22 +3,28 @@
 Tiling: grid ``(B, H, nq, nk)`` — the minor-most ``nk`` axis iterates
 sequentially on TPU, so the online-softmax state lives in VMEM scratch across
 ``nk`` steps and the output tile is emitted on the last one. Q/K/V tiles are
-``(block_q|block_k) × head_dim`` VMEM blocks (head_dim padded to a lane
-multiple of 128 by the wrapper in ``ops.py``); the MXU sees
+``(block_q|block_k) × head_dim`` VMEM blocks; ``head_dim`` is always the
+whole last dimension, so any head width tiles. The MXU sees
 ``block_q × head_dim × block_k`` matmuls.
+
+Sequence lengths need not divide the block: a sequence longer than its
+block is zero-padded up to a block multiple, padded keys are masked and
+padded query rows are dropped. A sequence no longer than the block is one
+block.
 
 Causal / sliding-window block skipping: fully-masked K blocks are skipped via
 ``pl.when`` — this is the ~2× causal FLOP saving the XLA chunked path cannot
-express (EXPERIMENTS.md §Perf).
+express.
 
 Validated against ``ref.attention_ref`` in interpret mode on CPU
-(tests/test_kernels.py); compiled path requires a real TPU.
+(tests/test_kernels.py) and compiled for a v5e chip
+(tests/test_chip_compile.py).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,7 +37,7 @@ NEG_INF = -1e30
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
             scale: float, causal: bool, window: Optional[int], q_start: int,
-            block_q: int, block_k: int, nk: int):
+            kv_len: int, block_q: int, block_k: int, nk: int):
     iq = pl.program_id(2)
     ik = pl.program_id(3)
 
@@ -64,6 +70,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
             mask &= kp <= qp
         if window is not None:
             mask &= kp > qp - window
+        if kv_len < nk * block_k:  # zero-padded keys
+            mask &= kp < kv_len
         s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_ref[...]
@@ -83,8 +91,16 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
+def _fit(n: int, block: int) -> Tuple[int, int]:
+    """(block, padded length): one whole-sequence block when ``n`` fits,
+    else ``block``-sized blocks over ``n`` rounded up to a block multiple."""
+    if n <= block:
+        return n, n
+    return block, -(-n // block) * block
+
+
 def flash_attention_pallas(
-    q: jnp.ndarray,  # (B, Sq, H, D) — D already lane-aligned by ops.py
+    q: jnp.ndarray,  # (B, Sq, H, D)
     k: jnp.ndarray,  # (B, Sk, K, D)
     v: jnp.ndarray,
     *,
@@ -97,21 +113,22 @@ def flash_attention_pallas(
 ) -> jnp.ndarray:
     B, Sq, H, D = q.shape
     Sk, K = k.shape[1], k.shape[2]
-    assert H % K == 0
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Sk)
-    assert Sq % block_q == 0 and Sk % block_k == 0, "pad sequences to block size"
-    nq, nk = Sq // block_q, Sk // block_k
+    if H % K:
+        raise ValueError(f"{H} query heads do not group over {K} kv heads")
+    block_q, Sq_pad = _fit(Sq, block_q)
+    block_k, Sk_pad = _fit(Sk, block_k)
+    nq, nk = Sq_pad // block_q, Sk_pad // block_k
     scale = 1.0 / (D ** 0.5)
 
-    # layout: (B, heads, seq, D) blocks
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
+    # layout: (B, heads, seq, D) blocks, seq zero-padded to a block multiple
+    def blocks(x, n):
+        return jnp.pad(x, ((0, 0), (0, n - x.shape[1]), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+
+    qt, kt, vt = blocks(q, Sq_pad), blocks(k, Sk_pad), blocks(v, Sk_pad)
 
     kernel = functools.partial(
         _kernel, scale=scale, causal=causal, window=window, q_start=q_start,
-        block_q=block_q, block_k=block_k, nk=nk,
+        kv_len=Sk, block_q=block_q, block_k=block_k, nk=nk,
     )
     out = pl.pallas_call(
         kernel,
@@ -122,7 +139,7 @@ def flash_attention_pallas(
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, iq, ik: (b, h * K // H, ik, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq_pad, D), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
@@ -130,4 +147,4 @@ def flash_attention_pallas(
         ],
         interpret=interpret,
     )(qt, kt, vt)
-    return out.transpose(0, 2, 1, 3)
+    return out[:, :, :Sq].transpose(0, 2, 1, 3)

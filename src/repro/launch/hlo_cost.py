@@ -199,10 +199,8 @@ def _dot_flops(comp: Computation, op: Op) -> int:
 
 
 def normalize_cost_analysis(cost) -> Dict[str, float]:
-    """``Compiled.cost_analysis()`` returns ``list[dict]`` on some jax
-    versions and ``dict`` (or ``None``) on others — always yield a dict."""
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else {}
+    """``Compiled.cost_analysis()`` returns a dict, or ``None`` where the
+    backend has no analysis — always yield a dict."""
     return cost or {}
 
 

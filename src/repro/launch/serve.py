@@ -13,6 +13,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.lm import build_model
 from repro.serving.engine import Request, ServingEngine
 
@@ -25,6 +26,7 @@ def main():
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--slots", type=int, default=4)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -19,6 +19,7 @@ Example (CPU, reduced config — exercised by examples/train_lm.py):
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import time
 from typing import Optional
@@ -30,12 +31,12 @@ import numpy as np
 from repro.configs import ARCH_IDS, get_config
 from repro.core.cluster import Cluster, Session
 from repro.data.pipeline import PipelineConfig, TokenPipeline, write_token_corpus
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_axis_info, make_mesh_for_devices
 from repro.models.lm import build_model
-from repro.parallel import sharding as shd
 from repro.storage.checkpoint import BlobCheckpointer
-from repro.train.optimizer import AdamWConfig, adamw_init
-from repro.train.trainer import make_train_step
+from repro.train.optimizer import AdamWConfig
+from repro.train.trainer import init_train_state, make_train_step
 
 
 def maybe_init_distributed() -> None:
@@ -55,7 +56,9 @@ def train(
     steps: int = 100,
     batch: int = 8,
     seq: int = 128,
+    n_layers: Optional[int] = None,  # cut depth, keep the config's widths
     model_parallel: int = 1,
+    n_devices: Optional[int] = None,  # the first n devices (default: all)
     checkpoint_every: int = 20,
     restore: bool = False,
     seed: int = 0,
@@ -66,24 +69,23 @@ def train(
     cfg = get_config(arch)
     if smoke:
         cfg = cfg.smoke()
-    import dataclasses
+    cfg = dataclasses.replace(
+        cfg, grad_accum=min(cfg.grad_accum, max(batch // 2, 1)),
+        n_layers=n_layers or cfg.n_layers,
+    )
 
-    cfg = dataclasses.replace(cfg, grad_accum=min(cfg.grad_accum, max(batch // 2, 1)))
-
-    mesh = make_mesh_for_devices(model_parallel=model_parallel)
+    mesh = make_mesh_for_devices(n_devices, model_parallel=model_parallel)
     axis_info = make_axis_info(mesh) if mesh.size > 1 else None
 
     model = build_model(cfg)
-    key = jax.random.PRNGKey(seed)
-    params, axes = model.init(key)
-    opt_state = adamw_init(params)
+    params, opt_state, shardings = init_train_state(
+        model, cfg, jax.random.PRNGKey(seed), axis_info
+    )
     opt_cfg = AdamWConfig(lr=lr, total_steps=steps, warmup_steps=max(steps // 10, 1))
     step_fn = make_train_step(model, cfg, axis_info, opt_cfg)
-    if axis_info is not None:
-        p_shard = shd.param_shardings(params, axes, cfg, axis_info)
-        o_shard = {"m": p_shard, "v": p_shard, "step": None}
-        jitted = jax.jit(step_fn, in_shardings=(p_shard, o_shard, None),
-                         out_shardings=(p_shard, o_shard, None), donate_argnums=(0, 1))
+    if shardings is not None:
+        jitted = jax.jit(step_fn, in_shardings=(*shardings, None),
+                         out_shardings=(*shardings, None), donate_argnums=(0, 1))
     else:
         jitted = jax.jit(step_fn, donate_argnums=(0, 1))
 
@@ -158,6 +160,7 @@ def main():
     ap.add_argument("--lr", type=float, default=3e-4)
     args = ap.parse_args()
     maybe_init_distributed()
+    use_compile_cache()
     out = train(
         args.arch, smoke=args.smoke, steps=args.steps, batch=args.batch,
         seq=args.seq, model_parallel=args.model_parallel,

@@ -15,7 +15,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.parallel.compat import shard_map
 
 from repro.kernels import ops
 from repro.models.config import ModelConfig
@@ -223,7 +222,7 @@ def attention_decode(
 
     pool_spec = P(page_axes)
     scale_spec = pool_spec if quant else P()
-    o, pool_k, pool_v, sk, sv, page_pos = shard_map(
+    o, pool_k, pool_v, sk, sv, page_pos = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(rep, rep, rep, pool_spec, pool_spec, scale_spec, scale_spec, rep, rep, rep),

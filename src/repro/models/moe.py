@@ -26,7 +26,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.parallel.compat import shard_map
 
 from repro.models.config import ModelConfig
 from repro.models.modules import dense_init
@@ -168,7 +167,7 @@ def moe_ffn(
         return out, aux
 
     xf = x.reshape(B * S, d)
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(batch_axes, None), P(None, None), w_spec, w_spec, w2_spec),

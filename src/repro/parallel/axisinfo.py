@@ -75,9 +75,7 @@ def constrain_batch(x, axis_info: Optional[AxisInfo]):
 
 def page_offset_in_shard(axis_names: Tuple[str, ...], pages_local: int):
     """Inside shard_map: first global page id owned by this rank."""
-    from repro.parallel.compat import axis_size
-
     idx = 0
     for name in axis_names:
-        idx = idx * axis_size(name) + jax.lax.axis_index(name)
+        idx = idx * jax.lax.axis_size(name) + jax.lax.axis_index(name)
     return idx * pages_local

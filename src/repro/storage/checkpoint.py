@@ -146,8 +146,8 @@ class BlobCheckpointer:
     def restore(self, step: Optional[int] = None, shardings: Any = None) -> Any:
         """Rebuild the state pytree from the blob (any retained step).
 
-        ``shardings``: optional pytree of NamedShardings to reshard onto a
-        (possibly different) mesh — elastic restart.
+        ``shardings``: optional sharding for every leaf, or a pytree of
+        them, to reshard onto a (possibly different) mesh — elastic restart.
         """
         with self._lock:
             if not self.checkpoints:
@@ -163,7 +163,7 @@ class BlobCheckpointer:
             leaves.append(arr)
         state = jax.tree.unflatten(self._treedef, leaves)
         if shardings is not None:
-            state = jax.tree.map(lambda x, s: jax.device_put(x, s), state, shardings)
+            state = jax.device_put(state, shardings)
         return state
 
     # -- retention ----------------------------------------------------------------------

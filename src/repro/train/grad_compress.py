@@ -19,7 +19,6 @@ from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
-from repro.parallel.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.parallel.axisinfo import AxisInfo
@@ -62,7 +61,7 @@ def compressed_pod_mean(grads, err_state, axis_info: AxisInfo, specs_tree, pod_a
         return tuple(o[0] for o in outs) + tuple(o[1] for o in outs)
 
     specs = tuple(flat_s) + tuple(flat_s)
-    outs = shard_map(
+    outs = jax.shard_map(
         wrapped, mesh=mesh, in_specs=specs, out_specs=specs, check_vma=False
     )(*flat_g, *flat_e)
     out_g = jax.tree.unflatten(treedef, outs[: len(flat_g)])

@@ -12,6 +12,7 @@ from jax import lax
 
 from repro.models.config import ModelConfig
 from repro.models.lm import Model
+from repro.parallel import sharding as shd
 from repro.parallel.axisinfo import AxisInfo
 from repro.train.optimizer import AdamWConfig, adamw_init, adamw_update
 
@@ -88,7 +89,23 @@ def make_train_step(model: Model, cfg: ModelConfig, axis_info: Optional[AxisInfo
     return train_step
 
 
-def init_train_state(model: Model, key) -> Tuple[Any, Any, Any]:
-    """(params, axes, opt_state) — concrete arrays (small configs / examples)."""
-    params, axes = model.init(key)
-    return params, axes, adamw_init(params)
+def init_train_state(model: Model, cfg: ModelConfig, key,
+                     axis_info: Optional[AxisInfo]) -> Tuple[Any, Any, Any]:
+    """``(params, opt_state, shardings)``, made by one jitted program that
+    writes every array straight into its final layout: sharded over the mesh
+    when there is one, so no device ever holds the whole state.
+    ``shardings`` is ``(param_shardings, opt_shardings)``, or ``None``
+    without a mesh."""
+    axes = {}
+
+    def init(k):
+        params, axes["tree"] = model.init(k)
+        return params, adamw_init(params)
+
+    if axis_info is None:
+        return (*jax.jit(init)(key), None)
+    params_shape, _ = jax.eval_shape(init, key)
+    p_shard = shd.param_shardings(params_shape, axes["tree"], cfg, axis_info)
+    o_shard = {"m": p_shard, "v": p_shard, "step": None}
+    params, opt_state = jax.jit(init, out_shardings=(p_shard, o_shard))(key)
+    return params, opt_state, (p_shard, o_shard)

@@ -1,103 +1,11 @@
-"""Test-suite bootstrap.
+"""Test-suite bootstrap: lock-order watchdog fixtures."""
 
-The property tests import :mod:`hypothesis`, which is not part of the baked
-container image (and installing packages is off-limits). When the real
-library is absent we register a minimal, deterministic stand-in that supports
-the subset used here — ``given``/``settings`` decorators and the
-``integers``/``sampled_from``/``composite`` strategies — drawing a fixed
-number of pseudo-random examples per test. With hypothesis installed, the
-stub steps aside entirely.
-"""
-
-from __future__ import annotations
-
-import functools
-import inspect
-import random
-import sys
-import types
-import zlib
-
-try:  # pragma: no cover - exercised only when hypothesis exists
-    import hypothesis  # noqa: F401
-except ImportError:
-
-    class _Strategy:
-        def __init__(self, draw_fn):
-            self._draw_fn = draw_fn
-
-        def example(self, rng: random.Random):
-            return self._draw_fn(rng)
-
-    def integers(min_value=0, max_value=1 << 30):
-        return _Strategy(lambda rng: rng.randint(min_value, max_value))
-
-    def sampled_from(elements):
-        elements = list(elements)
-        return _Strategy(lambda rng: elements[rng.randrange(len(elements))])
-
-    def composite(fn):
-        def build(*args, **kwargs):
-            def draw_fn(rng):
-                return fn(lambda strat: strat.example(rng), *args, **kwargs)
-
-            return _Strategy(draw_fn)
-
-        return build
-
-    def given(*given_args, **given_kwargs):
-        def decorate(fn):
-            # Like real hypothesis, positional strategies fill the RIGHTMOST
-            # parameters (leftmost ones stay available for pytest fixtures).
-            params = list(inspect.signature(fn).parameters.values())
-            n_pos = len(given_args)
-            drawn_names = [p.name for p in params[len(params) - n_pos :]]
-            remaining = params[: len(params) - n_pos]
-            remaining = [p for p in remaining if p.name not in given_kwargs]
-
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                n = getattr(wrapper, "_stub_max_examples", 20)
-                seed0 = zlib.crc32(fn.__name__.encode())
-                for i in range(n):
-                    rng = random.Random(seed0 + i)
-                    drawn_kw = dict(zip(drawn_names, (s.example(rng) for s in given_args)))
-                    drawn_kw.update({k: s.example(rng) for k, s in given_kwargs.items()})
-                    fn(*args, **kwargs, **drawn_kw)
-
-            # Hide the drawn parameters from pytest's fixture resolution.
-            wrapper.__signature__ = inspect.Signature(remaining)
-            del wrapper.__wrapped__
-            wrapper.hypothesis_stub = True
-            return wrapper
-
-        return decorate
-
-    def settings(max_examples=20, deadline=None, **_ignored):
-        def decorate(fn):
-            fn._stub_max_examples = max_examples
-            return fn
-
-        return decorate
-
-    stub = types.ModuleType("hypothesis")
-    stub.given = given
-    stub.settings = settings
-    strategies = types.ModuleType("hypothesis.strategies")
-    strategies.integers = integers
-    strategies.sampled_from = sampled_from
-    strategies.composite = composite
-    stub.strategies = strategies
-    sys.modules["hypothesis"] = stub
-    sys.modules["hypothesis.strategies"] = strategies
-
+import pytest
 
 # -- lockwatch integration ----------------------------------------------------
 # With REPRO_LOCKWATCH=1 every core lock is a WatchedLock reporting to the
 # process-global acquisition graph; these fixtures (no-ops otherwise) install
 # the join-under-lock hooks once and fail any test that recorded a violation.
-
-import pytest  # noqa: E402
 
 
 @pytest.fixture(scope="session", autouse=True)

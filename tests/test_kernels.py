@@ -30,6 +30,8 @@ FLASH_CASES = [
     (1, 256, 256, 2, 2, 64, True, 64, jnp.float32),  # sliding window
     (1, 512, 512, 2, 1, 64, True, 128, jnp.bfloat16),
     (2, 128, 256, 4, 4, 64, False, None, jnp.float32),  # cross (Sq != Sk)
+    (1, 200, 200, 4, 2, 32, True, 64, jnp.float32),  # padded to a block multiple
+    (1, 100, 330, 4, 2, 32, False, None, jnp.float32),  # padded keys masked
 ]
 
 
@@ -116,6 +118,36 @@ def test_paged_pallas_matches_ref(case):
     want = ref.paged_attention_ref(q, pool_k, pool_v, tables, page_pos, lengths, window=window)
     tol = 3e-2 if dtype == jnp.bfloat16 else 3e-5
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32), rtol=tol, atol=tol)
+
+
+def test_paged_pallas_engine_tables():
+    """The serving engine's batch: scattered pool pages, rows of different
+    lengths, table tails holding the out-of-range sentinel, and an idle row
+    (length 0, all sentinel) that must come out as exact zeros."""
+    B, T, H, K, D, R, P = 4, 8, 8, 2, 32, 6, 24
+    kk, kv, kq = jax.random.split(jax.random.PRNGKey(17), 3)
+    pool_k = rand(kk, (P, T, K, D), jnp.bfloat16)
+    pool_v = rand(kv, (P, T, K, D), jnp.bfloat16)
+    q = rand(kq, (B, H, D), jnp.bfloat16)
+    perm = np.random.default_rng(0).permutation(P)
+    lengths = np.array([45, 8, 0, 17], np.int32)
+    tables = np.full((B, R), P, np.int32)  # P: the engine's sentinel
+    for b, n in enumerate(lengths):
+        used = -(-n // T)
+        tables[b, :used] = perm[b * R : b * R + used]
+    page_pos = np.tile(np.arange(R, dtype=np.int32) * T, (B, 1))
+
+    o, m, l = paged_attention_pallas(
+        q, pool_k, pool_v, tables, page_pos, lengths, interpret=True
+    )
+    got = np.asarray(o) / np.maximum(np.asarray(l)[..., None], 1e-30)
+    want = ref.paged_attention_ref(q, pool_k, pool_v, jnp.asarray(tables),
+                                   jnp.asarray(page_pos), jnp.asarray(lengths))
+    live = lengths > 0
+    np.testing.assert_allclose(got[live], np.asarray(want, np.float32)[live],
+                               rtol=3e-2, atol=3e-2)
+    np.testing.assert_array_equal(np.asarray(l)[~live], 0.0)
+    np.testing.assert_array_equal(np.asarray(o)[~live], 0.0)
 
 
 @pytest.mark.parametrize("case", PAGED_CASES[:3])
