@@ -22,9 +22,10 @@ level   locks
         deliberately held across data-plane copies (one aliases the other
         when both actors exist)
 3       per-object bookkeeping locks that guard small registries and windows
-        (session lists, async-write windows, coalesce queues, pin flags)
+        (async-write windows, coalesce queues, pin flags, node fences)
 4       the shared actors' state locks (version manager, provider manager,
-        pin table, balancer heat counters, aux-pool bring-up)
+        pin table, GC coordinator, balancer heat counters, session lists,
+        aux-pool bring-up)
 5       leaf locks: per-cache, per-provider, per-stats — never hold anything
         else while holding one of these
 ======  ======================================================================
@@ -79,12 +80,6 @@ LOCKS = [
     LockSpec("ReplicaBalancer._rebalance_lock", 2, allow_blocking=True,
              note="readers try-lock and skip; held across page copies so "
                   "promotions serialize without queueing the read path"),
-    LockSpec("Federation._fence_lock", 2, allow_blocking=True,
-             note="per-node fence/rejoin transitions (one instance per "
-                  "node); held across the node's cache purges (level 5) "
-                  "and the coordinator join (level 3), so it must sit "
-                  "BELOW the coordinator lock. Never nests the repair/"
-                  "rebalance locks of this level"),
     LockSpec("RepairService._lock", 2, allow_blocking=True,
              note="re-replication/scrub passes; held across data-plane "
                   "copies like the rebalance lock. On clusters WITH a "
@@ -93,7 +88,12 @@ LOCKS = [
                   "promotion and GC exclusion all serialize on one lock "
                   "(same level: the two names must never nest)"),
     # -- level 3: small registries / windows ---------------------------------
-    LockSpec("Cluster._sessions_lock", 3),
+    LockSpec("Federation._fence_lock", 3, allow_blocking=True,
+             note="per-node fence/rejoin transitions (one instance per "
+                  "node); held across the node's cache purges (level 5), "
+                  "its session list and the coordinator join (level 4). A "
+                  "chaos harness may rejoin a node from a fault gate inside "
+                  "a GC sweep, so it sits below the pass's level-2 locks"),
     LockSpec("Cluster._membership_lock", 3),
     LockSpec("Cluster._warmers_lock", 3),
     LockSpec("Session._async_lock", 3),
@@ -106,14 +106,6 @@ LOCKS = [
     LockSpec("MetadataDHT._coalesce_lock", 3),
     LockSpec("MetadataDHT._executor_lock", 3),
     LockSpec("BlobStore._handles_lock", 3),
-    LockSpec("GcEpochCoordinator._lock", 3,
-             note="epoch counter, per-node leases, federated pin tables "
-                  "and node health; no RPC ever runs under it"),
-    LockSpec("GcEpochCoordinator._cv", 3,
-             note="condition ALIASING GcEpochCoordinator._lock (the "
-                  "VersionManager._published_cv pattern): pins wait on it "
-                  "while a GC sweep is in progress; nesting the two names "
-                  "is a self-deadlock"),
     LockSpec("FaultInjector._lock", 3,
              note="guards the chaos harness's op counter and pending "
                   "fault queues; fault ACTIONS (kill/recover/sleep) run "
@@ -129,6 +121,10 @@ LOCKS = [
                   "always called with this RELEASED"),
     # -- level 4: shared-actor state -----------------------------------------
     LockSpec("Cluster._aux_lock", 4),
+    LockSpec("Cluster._sessions_lock", 4),
+    LockSpec("GcEpochCoordinator._lock", 4,
+             note="epoch counter, per-node leases, federated pin tables "
+                  "and node health; no RPC ever runs under it"),
     LockSpec("Cluster._pins_lock", 4),
     LockSpec("VersionManager._lock", 4),
     LockSpec("VersionManager._published_cv", 4,

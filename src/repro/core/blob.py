@@ -193,7 +193,9 @@ class BlobStore:
     ) -> int:
         return self._handle(blob_id).write_unaligned(buffer, offset_bytes)
 
-    def gc(self, blob_id: int, keep_versions: Sequence[int]) -> Tuple[int, int]:
+    def gc(
+        self, blob_id: int, keep_versions: Sequence[int]
+    ) -> Tuple[int, int, int]:
         return self.cluster.gc(blob_id, keep_versions)
 
     def storage_bytes(self) -> int:

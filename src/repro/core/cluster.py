@@ -380,7 +380,8 @@ class Cluster:
         #: strictly before a GC pass reads the pin set (and is honored) or
         #: strictly after the pass completes — never mid-sweep, where the
         #: just-pinned version could still be collected (``_pins_lock`` alone
-        #: cannot give that guarantee; it is held only for the dict ops)
+        #: cannot give that guarantee; it is held only for the dict ops).
+        #: A Federation makes every node share its home node's guard.
         self._gc_guard = make_lock("Cluster._gc_guard")
         #: cluster-wide content-addressed page registry (the serving plane's
         #: cross-user prefix cache): published pages keyed by content, each
@@ -416,6 +417,10 @@ class Cluster:
         #: must be visible to GC passes initiated from ANY node)
         self._pin_sink: Optional[Callable[[int, int], None]] = None
         self._unpin_sink: Optional[Callable[[int, int], None]] = None
+        #: death verdicts against this node so far; each one reclaimed the
+        #: node's pins at the coordinator, so a snapshot taken before it may
+        #: have lost its version to a GC pass
+        self._death_verdicts = 0
 
     # -- sessions ------------------------------------------------------------
     def session(
@@ -612,7 +617,7 @@ class Cluster:
         blob_id: int,
         keep_versions: Sequence[int],
         _local: bool = False,
-    ) -> Tuple[int, int]:
+    ) -> Tuple[int, int, int]:
         """Drop all tree nodes / pages unreachable from ``keep_versions``
         (plus every snapshot-pinned version — a live :class:`Snapshot` keeps
         its version readable no matter what the GC caller asks for).
@@ -626,7 +631,12 @@ class Cluster:
         protocol before remote nodes' caches can be trusted). Promotion
         passes are paused for the duration, and snapshot creation serializes
         against the pass (``_gc_guard``), so a pin can never land mid-sweep
-        and lose its version. Returns (nodes_freed, pages_freed).
+        and lose its version.
+
+        A pass also keeps the newest published version as of its own start
+        (its *frontier*): writes in flight during the pass weave their trees
+        onto that version, so collecting any of it would lose their data
+        once they publish. Returns ``(nodes_freed, pages_freed, frontier)``.
 
         On a federated node this delegates to ``Federation.gc`` — versions
         are reclaimed only under the epoch/lease protocol, after every live
@@ -635,32 +645,30 @@ class Cluster:
         fed = self._federation
         if fed is not None and not _local:
             return fed.gc(blob_id, keep_versions)
+        keep = set(keep_versions)
         with self._gc_guard:
-            keep = set(keep_versions) | self.pinned_versions(blob_id)
+            pinned = self.pinned_versions(blob_id)
             if fed is not None:
-                # the coordinator's sweep window opens INSIDE this node's
-                # gc guard: coordinator pins are snapshotted here, and pin
-                # requests from other nodes block until the sweep closes —
-                # the federated analog of the single-node pin linearization
-                # (pinners on THIS node block on the gc guard itself)
-                keep |= fed.coordinator.begin_sweep(blob_id)
-            try:
-                if self.replica_balancer is not None:
-                    # repair_service aliases the balancer's _rebalance_lock,
-                    # so pausing the balancer excludes repair passes too
-                    with self.replica_balancer.paused():
-                        return self._gc_locked(blob_id, keep)
-                with self.repair_service.paused():
-                    return self._gc_locked(blob_id, keep)
-            finally:
-                if fed is not None:
-                    fed.coordinator.end_sweep()
+                # every federated node shares this guard, so the
+                # coordinator's pin set (every node's pins) is final here
+                pinned |= fed.coordinator.pinned_versions(blob_id)
+            if self.replica_balancer is not None:
+                # repair_service aliases the balancer's _rebalance_lock,
+                # so pausing the balancer excludes repair passes too
+                with self.replica_balancer.paused():
+                    return self._gc_locked(blob_id, keep, pinned)
+            with self.repair_service.paused():
+                return self._gc_locked(blob_id, keep, pinned)
 
-    def _gc_locked(self, blob_id: int, keep_versions: Set[int]) -> Tuple[int, int]:
+    def _gc_locked(
+        self, blob_id: int, keep_versions: Set[int], pinned: Set[int]
+    ) -> Tuple[int, int, int]:
         vm = self.version_manager
         total_pages, _ = vm.blob_info(blob_id)
         latest = vm.latest_published(blob_id)
-        keep = sorted(v for v in keep_versions if v != ZERO_VERSION)
+        # in-flight writes weave their trees onto the newest published
+        # version, so the pass never collects what it links to
+        keep = sorted((set(keep_versions) | {latest}) - {ZERO_VERSION})
         aborted = vm.aborted_view(blob_id)
         reachable_nodes: Set[NodeKey] = set()
         reachable_pages: Set[PageRef] = set()
@@ -687,6 +695,15 @@ class Cluster:
 
         for v in keep:
             mark(v, 0, total_pages)
+        for v in sorted(pinned.difference(keep, {ZERO_VERSION})):
+            try:
+                mark(v, 0, total_pages)
+            except KeyError:
+                if NodeKey(blob_id, v, 0, total_pages) in reachable_nodes:
+                    raise  # the root exists but its tree does not
+                # collected before this pin registered (a snapshot of a
+                # version an earlier pass dropped, or a dead node's pins
+                # restored on rejoin): the pin has nothing left to protect
 
         # Enumerate every stored node of this blob and drop unreachable ones.
         doomed_nodes: List[NodeKey] = []
@@ -716,12 +733,12 @@ class Cluster:
         # versions stay cached — their pages were not collected above, and a
         # concurrent writer's write-through entries must survive another
         # session's GC call.
-        keep_cached = set(keep) | {ZERO_VERSION}
+        keep_cached = set(keep) | pinned | {ZERO_VERSION}
         caches = [self.shared_cache] + [s.cache for s in self.sessions()]
         for cache in caches:
             if cache is not None:
                 cache.drop_versions(blob_id, keep_cached, max_version=latest)
-        return len(doomed_nodes), len(doomed_pages)
+        return len(doomed_nodes), len(doomed_pages), latest
 
     # -- introspection --------------------------------------------------------
     def storage_bytes(self) -> int:
@@ -1209,12 +1226,12 @@ class Session:
 
         The doomed versions are withdrawn first; what happens to their
         stored wreckage depends on how :meth:`VersionManager.abandon`
-        resolved them. Fully *erased* versions (no concurrent writer assigned
-        after them) are scrubbed: pages deleted, nodes deleted, placement
-        credits released. Versions that became publication *holes* are left
-        in place instead — a later writer may already have woven border links
-        into their trees, so deleting whatever did land would turn that
-        writer's published version unreadable; the wreckage stays until
+        resolved them. Versions no live writer can link to (none assigned
+        after them is live) are scrubbed: pages deleted, nodes deleted,
+        placement credits released. Holes a live writer may link to are left
+        in place instead — that writer may already have woven border links
+        into their trees, so deleting whatever did land would turn its
+        published version unreadable; the wreckage stays until
         :meth:`Cluster.gc` collects it (which also returns the load
         credit), the same stance taken for orphans on a down provider."""
         provider_manager = self.cluster.provider_manager
@@ -1997,6 +2014,7 @@ class Snapshot:
         self._page_size = page_size
         self._pinned = True
         self._pin_lock = make_lock("Snapshot._pin_lock")
+        self._death_verdicts = handle.session.cluster._death_verdicts
 
     @property
     def blob_id(self) -> int:
@@ -2010,10 +2028,22 @@ class Snapshot:
         return self.readv([(offset_bytes, size_bytes)])[0]
 
     def readv(self, segments: Sequence[Tuple[int, int]]) -> List[np.ndarray]:
-        return self.handle.session._readv(
-            self.handle.blob_id, self.version, segments,
-            self._total_pages, self._page_size,
-        )
+        """Read through the pin. Raises ``ProviderFailed`` when the version
+        is gone because a death verdict against this (federated) node
+        reclaimed the pin after the snapshot was taken."""
+        cluster = self.handle.session.cluster
+        try:
+            return self.handle.session._readv(
+                self.handle.blob_id, self.version, segments,
+                self._total_pages, self._page_size,
+            )
+        except KeyError:
+            if cluster._death_verdicts == self._death_verdicts:
+                raise
+            raise ProviderFailed(
+                f"snapshot of version {self.version}: node "
+                f"{cluster._node_id} was declared dead and lost its pins"
+            ) from None
 
     def release(self) -> None:
         """Drop the GC pin (idempotent). Reads remain possible afterwards but
@@ -2055,15 +2085,9 @@ class VersionWatch:
             if deadline is not None:
                 remaining = max(0.0, deadline - time.monotonic())
             try:
-                # fail_on_withdrawn=False: an erased version number may be
-                # reissued to the next writer, and the watch must deliver it
-                # then — only aborted holes (never readable) raise, and those
-                # are stepped over without delivery
-                if not self._vm.wait_published(
-                    self.blob_id, target, remaining, fail_on_withdrawn=False
-                ):
+                if not self._vm.wait_published(self.blob_id, target, remaining):
                     return None
-            except VersionAbandoned:
+            except VersionAbandoned:  # a hole: stepped over, never delivered
                 self.last_delivered = target
                 continue
             self.last_delivered = target

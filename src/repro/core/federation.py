@@ -52,7 +52,7 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 
-from repro.analysis.lockwatch import make_condition, make_lock
+from repro.analysis.lockwatch import make_lock
 from repro.core.cluster import DEFAULT_SHARED_CACHE_BYTES, Cluster
 from repro.core.dht import (
     HealthConfig,
@@ -78,11 +78,11 @@ NODE_KILLED = "killed"
 class GcEpochCoordinator:
     """Epoch counter + per-node leases + federated snapshot pins.
 
-    All state lives under ONE level-3 lock; no method blocks while holding
-    it except :meth:`pin`, which waits on the aliased condition while a GC
-    sweep is in progress (the federated analog of the single-node
-    ``_gc_guard`` pin linearization — a pin lands strictly before the sweep
-    reads the pin set, or strictly after the sweep completes).
+    All state lives under ONE level-4 lock and no method blocks while
+    holding it. Pins never land mid-sweep because every node of a
+    :class:`Federation` shares the home node's ``_gc_guard``: a pin
+    (resolve + register) on any node and the home node's sweep exclude
+    each other, exactly as on a single node.
 
     Lease semantics:
 
@@ -109,7 +109,6 @@ class GcEpochCoordinator:
         self.clock = clock
         self.health_config = health or HealthConfig()
         self._lock = make_lock("GcEpochCoordinator._lock")
-        self._cv = make_condition("GcEpochCoordinator._cv", lock=self._lock)
         self._epoch = 1
         #: node -> epoch it last joined at
         self._lease_epoch: Dict[int, int] = {}
@@ -120,8 +119,6 @@ class GcEpochCoordinator:
         #: node health: failure timestamps within the window + sticky deaths
         self._failures: Dict[int, List[float]] = {}
         self._dead: Set[int] = set()
-        #: a GC storage sweep is in progress: pins wait it out
-        self._sweeping = False
 
     # -- epoch / leases ------------------------------------------------------
     def epoch(self) -> int:
@@ -186,12 +183,9 @@ class GcEpochCoordinator:
 
     # -- federated snapshot pins ---------------------------------------------
     def pin(self, node_id: int, blob_id: int, version: int) -> None:
-        """Register a snapshot pin for ``node_id``. Blocks while a GC sweep
-        is in progress — the pin then lands strictly after the pass (whose
-        reclaim it could no longer veto), never mid-sweep."""
-        with self._cv:
-            while self._sweeping:
-                self._cv.wait()
+        """Register a snapshot pin for ``node_id`` (refused once the node is
+        declared dead)."""
+        with self._lock:
             if node_id in self._dead:
                 raise ProviderFailed(
                     f"node {node_id} is declared dead; pin refused"
@@ -222,12 +216,14 @@ class GcEpochCoordinator:
         unreachable are swallowed best-effort on the node side, so without
         this the coordinator would protect the released versions forever;
         conversely a revived node re-registers the pins its death verdict
-        reclaimed. Blocks while a sweep is in progress, like :meth:`pin` —
-        re-added pins land strictly after the pass they could no longer
-        veto."""
-        with self._cv:
-            while self._sweeping:
-                self._cv.wait()
+        reclaimed.
+
+        This never waits for a sweep: the rejoin that calls it can run on
+        the sweeping thread itself (a fault gate fires a node recover inside
+        the pass's own metadata RPCs). A pass reads the pin set once, at its
+        start, so pins replaced mid-sweep veto nothing in it, exactly as
+        pins replaced after it would not."""
+        with self._lock:
             if pins:
                 self._pins[node_id] = dict(pins)
             else:
@@ -243,23 +239,6 @@ class GcEpochCoordinator:
                 for (b, v) in pins
                 if b == blob_id
             }
-
-    def begin_sweep(self, blob_id: int) -> Set[int]:
-        """Open the sweep window: returns the pin snapshot for ``blob_id``
-        and blocks new pins until :meth:`end_sweep`."""
-        with self._lock:
-            self._sweeping = True
-            return {
-                v
-                for pins in self._pins.values()
-                for (b, v) in pins
-                if b == blob_id
-            }
-
-    def end_sweep(self) -> None:
-        with self._cv:
-            self._sweeping = False
-            self._cv.notify_all()
 
     # -- node health (live -> suspect -> dead) --------------------------------
     def note_failure(self, node_id: int) -> bool:
@@ -409,6 +388,10 @@ class Federation:
         self._fence_locks.append(make_lock("Federation._fence_lock"))
         node._federation = self
         node._node_id = i
+        if self.nodes:
+            # one gc guard federation-wide: a pin on any node (resolve +
+            # register) is atomic with respect to the home node's sweep
+            node._gc_guard = self.nodes[0]._gc_guard
         node._pin_sink = (
             lambda blob_id, version, i=i: self._pin_from_node(
                 i, blob_id, version
@@ -511,7 +494,7 @@ class Federation:
     # -- federated GC ----------------------------------------------------------
     def gc(
         self, blob_id: int, keep_versions: Sequence[int]
-    ) -> Tuple[int, int]:
+    ) -> Tuple[int, int, int]:
         """The epoch/lease GC protocol; called via any node's
         ``Cluster.gc`` (which delegates here) or directly.
 
@@ -524,12 +507,14 @@ class Federation:
         3. a node that is unreachable but not dead is **waited out**: its
            lease expiry bounds the stall (``epoch_stalls``), and expiry
            guarantees the node fences before its next cache serve;
-        4. sweep storage on the home node (whose local GC re-reads the
-           coordinator pin set inside its gc guard, blocking new pins for
-           the sweep's duration).
+        4. sweep storage on the home node (whose local GC reads the
+           coordinator pin set inside the federation-wide gc guard, which
+           blocks new pins on every node for the sweep's duration).
 
         Like single-node GC, the caller promises no concurrent accesses
-        target the dropped versions."""
+        target the dropped versions, and the sweep keeps the newest
+        published version as of its own start, which in-flight writes weave
+        onto. Returns ``(nodes_freed, pages_freed, frontier)`` of the sweep."""
         home = self.nodes[0]
         with self._gc_lock:
             epoch = self.coordinator.advance_epoch()
@@ -624,6 +609,7 @@ class Federation:
         self.coordinator.reclaim(i)
         with self._fence_locks[i]:
             self._fenced[i] = True
+            node._death_verdicts += 1
         self.repair_service.recover_writers(node.sessions())
 
     # -- node-plane faults (chaos harness) -------------------------------------
@@ -653,7 +639,11 @@ class Federation:
         best-effort (the coordinator would otherwise protect the released
         versions forever), and a death verdict reclaimed pins the node's
         live snapshots still hold. The mode flips to ``up`` only after the
-        resync, so no new pin can interleave with the snapshot."""
+        resync, so no new pin can interleave with the snapshot.
+
+        Nothing here waits for a GC sweep to close: the chaos harness applies
+        a due ``recover`` on whichever thread's RPC reaches it, and that may
+        be the thread running the sweep."""
         self.coordinator.revive(i)
         with self._fence_locks[i]:
             self.nodes[i].fence_caches()

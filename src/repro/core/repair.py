@@ -10,7 +10,9 @@ running degraded until someone restores the replication factor. The
 :class:`RepairService` is that someone:
 
 * **Re-replication** (:meth:`RepairService.run_once`): for every published
-  leaf with a replica on a dead (or failure-flagged) provider, copy the page
+  leaf with a replica on a dead (or failure-flagged) provider, or with fewer
+  than ``replication`` copies (an earlier pass found no verified source or
+  no healthy target), copy the page
   from a surviving replica onto healthy providers until ``replication``
   copies exist again, then re-put the leaf with the corrected ref set — the
   same sanctioned placement-only leaf rewrite the replica balancer performs,
@@ -169,8 +171,6 @@ class RepairService:
         vm = self.cluster.version_manager
         metadata = self.cluster.metadata
         down = self._unavailable_pids()
-        if not down:
-            return 0
         published, aborted = vm.repair_horizon(blob_id)
         corrected: List[TreeNode] = []
         released: List[PageRef] = []
@@ -182,8 +182,8 @@ class RepairService:
                 continue  # in-flight writers fix their own placements
             refs = node.all_page_refs()
             lost = [r for r in refs if r[0] in down]
-            if not lost:
-                continue
+            if not lost and len(refs) >= pm.replication:
+                continue  # whole (a leaf an earlier pass left short is not)
             survivors = [r for r in refs if r[0] not in down]
             if not survivors:
                 continue  # every replica down at once: unrepairable
@@ -199,6 +199,8 @@ class RepairService:
                     holders.add(placed[0])
                     fresh.append(placed)
                 repaired += len(fresh)
+            if not lost and not fresh:
+                continue  # still short of copies: nothing to rewrite yet
             # rewrite the leaf without the lost refs even when no fresh copy
             # could be placed — readers must stop dialing dead providers
             new_refs = survivors + fresh
@@ -293,7 +295,7 @@ class RepairService:
     def recover_writers(self, sessions) -> int:
         """Scrub after a *node* death (federated mode): every session of the
         dead node may hold assigned-but-unreported versions that would wedge
-        in-order publication forever. Abandon them (erase or hole, per
+        in-order publication forever. Abandon them (as holes, per
         :meth:`VersionManager.abandon`), then scrub the holes' wreckage so
         the storage space comes back. Idempotent — versions the writer
         already aborted itself are skipped by ``abandon``. Returns the

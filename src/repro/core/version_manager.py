@@ -69,12 +69,6 @@ class _BlobState:
     #: manager lock — read paths grab it lock-free to decide whether the
     #: aborted-link redirect machinery needs to engage at all
     aborted_view: frozenset = frozenset()
-    #: versions fully *erased* by abandon (they were the latest assignment, so
-    #: interval history rolled back) and not yet reassigned to a new writer.
-    #: Publication can never reach them until reassignment, so waiters treat
-    #: them exactly like aborted holes and fail fast; ``assign_versions``
-    #: clears a number from here the moment a new writer takes it.
-    withdrawn: set = dataclasses.field(default_factory=set)
     #: per-page latest assigned version, for O(range-max) border queries
     page_versions: Optional[np.ndarray] = None
 
@@ -158,7 +152,6 @@ class VersionManager:
             out: List[Tuple[int, List[BorderLink]]] = []
             for offset, size in spans:
                 version = st.assigned + 1
-                st.withdrawn.discard(version)  # the number has a writer again
                 links = compute_border_links(
                     st.total_pages, offset, size, version_of_segment
                 )
@@ -189,9 +182,7 @@ class VersionManager:
             # was mid-flight, the write MUST surface as a failure — marking
             # an aborted hole "complete" would silently ack a write that
             # will never publish
-            stale = sorted(
-                v for v in versions if v in st.aborted or v in st.withdrawn
-            )
+            stale = sorted(v for v in versions if v in st.aborted)
             if stale:
                 raise VersionAbandoned(
                     f"versions {stale} of blob {blob_id} were abandoned "
@@ -207,26 +198,24 @@ class VersionManager:
         """Withdraw assigned-but-unreportable versions after a failed WRITE.
 
         Without this, in-order publication would wedge forever behind a
-        version whose writer died mid-flight. Two cases, handled newest-first:
+        version whose writer died mid-flight. Every abandoned version becomes
+        an *aborted hole*: publication skips over it, reads of it and a late
+        report from its writer are rejected, and its interval stays in the
+        history, but the per-page latest-version array is rolled back past it
+        so every writer assigned *from now on* links straight to live
+        versions. A version number is never handed out twice: writer recovery
+        abandons the versions of writers that may still be live (a
+        partitioned node), whose late node puts, cleanup and success report
+        would otherwise land on the number's next owner.
 
-        * the version is still the *latest* assignment — it is fully erased
-          (interval history and the per-page version array are rolled back),
-          so no future border link can ever reference it and the version
-          number is reused by the next writer;
-        * a concurrent writer was assigned after it — the version becomes an
-          *aborted hole*: publication skips over it, reads of it are
-          rejected, and its interval stays in the history, but the per-page
-          latest-version array is rolled back past it so every writer
-          assigned *from now on* links straight to live versions. Writers
-          assigned *before* the abandon may already have woven border links
-          against the hole; those dangling links are resolved on the read
-          path via :meth:`redirect_read_link` and eventually unlinked by the
-          repair service's metadata scrub.
-
-        Returns the set of versions that became holes (empty when everything
-        was erased) — the caller must NOT scrub a hole's stored pages/nodes
-        inline, since pre-abandon writers' trees may reference them (the
-        scrub runs later, once the read-path redirect makes it safe).
+        Writers assigned *before* the abandon may already have woven border
+        links against a hole; those dangling links are resolved on the read
+        path via :meth:`redirect_read_link` and eventually unlinked by the
+        repair service's metadata scrub. Returns the holes a live writer may
+        link to (a version assigned after the hole is still live) — the
+        caller must NOT scrub those inline (the scrub runs later, once the
+        read-path redirect makes it safe); the other versions it abandoned
+        nothing can reference, so it may scrub them at once.
         """
         holes: set = set()
         with self._lock:
@@ -251,6 +240,12 @@ class VersionManager:
                         )
                 return seg
 
+            def newest_live(v: int) -> int:
+                while v in st.aborted:
+                    v -= 1
+                return v
+
+            top = newest_live(st.assigned)
             for v in sorted(set(versions), reverse=True):
                 if (
                     v <= st.published
@@ -260,24 +255,19 @@ class VersionManager:
                 ):
                     continue  # published/completed versions are past abandoning
                 self.journal.append(JournalEntry("abandon", blob_id, v))
-                if v == st.assigned:
-                    offset, size = st.intervals.pop(v)
-                    st.assigned -= 1
-                    st.withdrawn.add(v)
-                    pv[offset : offset + size] = rolled_back(offset, size)
-                else:
-                    st.aborted.add(v)
+                st.aborted.add(v)
+                if v < top:
                     holes.add(v)
-                    # roll pv back over the hole too: pages still carrying v
-                    # recompute from live history, pages a later writer
-                    # already overwrote stay theirs
-                    offset, size = st.intervals[v]
-                    span = pv[offset : offset + size]
-                    mine = span == v
-                    if mine.any():
-                        span[mine] = rolled_back(offset, size)[mine]
-            if holes:
-                st.aborted_view = frozenset(st.aborted)
+                else:
+                    top = newest_live(v)
+                # pages still carrying v recompute from live history, pages
+                # a later writer already overwrote stay theirs
+                offset, size = st.intervals[v]
+                span = pv[offset : offset + size]
+                mine = span == v
+                if mine.any():
+                    span[mine] = rolled_back(offset, size)[mine]
+            st.aborted_view = frozenset(st.aborted)
             self._advance_published_locked(st)
         return holes
 
@@ -393,35 +383,23 @@ class VersionManager:
         blob_id: int,
         version: int,
         timeout: Optional[float] = None,
-        *,
-        fail_on_withdrawn: bool = True,
     ) -> bool:
         """Block until ``version`` publishes; ``False`` on timeout.
 
         Raises :class:`VersionAbandoned` when ``version`` was withdrawn by a
-        failed writer — whether it became an aborted hole or was erased
-        outright. :meth:`abandon` notifies this condition, so a waiter whose
-        version is abandoned *mid-wait* fails fast instead of burning its
-        whole timeout on a version that can never arrive as written.
-
-        ``fail_on_withdrawn=False`` is for subscription waiters
-        (:class:`~repro.core.cluster.VersionWatch`): an *erased* version
-        number may be reissued to the next writer, so a watch keeps waiting
-        for the number to publish under its new owner — only aborted holes
-        (which can never publish) raise."""
+        failed writer (an aborted hole). :meth:`abandon` notifies this
+        condition, so a waiter whose version is abandoned *mid-wait* fails
+        fast instead of burning its whole timeout on a version that can
+        never arrive as written."""
         st = self._blobs[blob_id]
 
         def resolved() -> bool:
-            if st.published >= version or version in st.aborted:
-                return True
-            return fail_on_withdrawn and version in st.withdrawn
+            return st.published >= version or version in st.aborted
 
         with self._published_cv:
             if not self._published_cv.wait_for(resolved, timeout=timeout):
                 return False
-            if version in st.aborted or (
-                fail_on_withdrawn and version in st.withdrawn
-            ):
+            if version in st.aborted:
                 raise VersionAbandoned(
                     f"version {version} of blob {blob_id} was abandoned by a "
                     f"failed writer"

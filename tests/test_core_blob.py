@@ -1,6 +1,8 @@
 """Unit + property tests for the faithful blob-store reproduction, driven
 through the layered Cluster / Session / BlobHandle API."""
 
+import threading
+
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -110,12 +112,53 @@ def test_gc_keeps_reachable_shared_pages():
     handle.write(base, 0)  # v1
     patch = np.full(PAGE, 5, np.uint8)
     handle.write(patch, 4 * PAGE)  # v2 shares 15 pages with v1
-    nodes_freed, pages_freed = cluster.gc(handle.blob_id, keep_versions=[2])
+    nodes_freed, pages_freed, frontier = cluster.gc(
+        handle.blob_id, keep_versions=[2]
+    )
+    assert frontier == 2
     assert pages_freed == 1  # only v1's overwritten page dies
     assert nodes_freed > 0  # v1's root path dies
     expect = base.copy()
     expect[4 * PAGE : 5 * PAGE] = patch
     np.testing.assert_array_equal(handle.read(0, 16 * PAGE, version=2).data, expect)
+
+
+def test_gc_keeps_what_an_inflight_write_weaves_onto():
+    """Regression: a write in flight during a GC pass has woven its tree
+    onto the newest published version; a pass asked to keep only an older
+    version must still keep what the in-flight write links to."""
+    cluster = make_cluster()
+    handle = cluster.session().create(16 * PAGE, PAGE)
+    base = np.ones(16 * PAGE, np.uint8)
+    handle.write(base, 0)  # v1
+    handle.write(np.full(4 * PAGE, 2, np.uint8), 8 * PAGE)  # v2: the frontier
+    held, release = threading.Event(), threading.Event()
+
+    def gate(op, shard_id):
+        if op == "put_many" and not held.is_set():
+            held.set()  # the write has its version and its border links
+            release.wait(10.0)
+
+    for shard in cluster.metadata.shards:
+        shard.fault_gate = gate
+    writer = cluster.session().open(handle.blob_id)
+    patch = np.full(PAGE, 3, np.uint8)
+    versions = []
+    t = threading.Thread(
+        target=lambda: versions.append(writer.write(patch, 0)), daemon=True
+    )
+    t.start()
+    assert held.wait(10.0)
+    cluster.gc(handle.blob_id, keep_versions=[1])
+    release.set()
+    t.join(10.0)
+    assert not t.is_alive() and versions == [3]
+    expect = base.copy()
+    expect[8 * PAGE : 12 * PAGE] = 2
+    expect[:PAGE] = 3
+    np.testing.assert_array_equal(
+        handle.read(0, 16 * PAGE, version=3).data, expect
+    )
 
 
 def test_elastic_provider_join():

@@ -411,7 +411,7 @@ def test_gc_demotes_and_forgets_promoted_pages():
     assert n_promoted > 0
     promoted = bal.promoted_refs(key)
     handle.write(np.full(16 * PAGE, 2, np.uint8), 0)  # v2 rewrites all
-    nodes_freed, pages_freed = cluster.gc(handle.blob_id, keep_versions=[2])
+    _, pages_freed, _ = cluster.gc(handle.blob_id, keep_versions=[2])
     # v1's 16 pages die, including the promoted copies of the hot page
     assert pages_freed == 16 + n_promoted
     assert bal.promoted_refs(key) == ()

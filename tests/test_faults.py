@@ -11,6 +11,7 @@ repair.
 """
 
 import threading
+import traceback
 
 import numpy as np
 import pytest
@@ -327,6 +328,43 @@ def test_repair_restores_replication_factor():
     cluster.close()
 
 
+def test_repair_tops_up_leaves_an_earlier_pass_left_short():
+    """A pass with no healthy target still drops the dead provider's refs;
+    a later pass, with nothing down any more, must restore the leaves to
+    the replication factor."""
+    cluster = Cluster(n_data_providers=2, page_replication=2,
+                      shared_cache_bytes=0, health=HealthConfig(dead_after=1))
+    pm = cluster.provider_manager
+    pm.on_dead = None  # drive the passes by hand for determinism
+    sess = cluster.session(cache_bytes=0)
+    handle = sess.create(4 * PAGE, PAGE)
+    data = np.random.default_rng(5).integers(0, 255, 4 * PAGE, dtype=np.uint8)
+    v = handle.write(data, 0)
+
+    def leaf_refs():
+        return [
+            node.all_page_refs()
+            for _, node in cluster.metadata.iter_nodes(handle.blob_id)
+            if node.is_leaf
+        ]
+
+    pm.fail_provider(0)
+    pm.note_failure(0)
+    cluster.repair_service.run_once()  # no target for the lost copies
+    assert all({pid for pid, _ in refs} == {1} for refs in leaf_refs())
+    pm.recover_provider(0)
+    repaired, _ = cluster.repair_service.run_once()
+    assert repaired == 4
+    for refs in leaf_refs():
+        assert {pid for pid, _ in refs} == {0, 1}
+        for pid, page_key in refs:
+            assert pm.get_provider(pid).has_page(page_key)
+    np.testing.assert_array_equal(
+        sess.open(handle.blob_id).read(0, 4 * PAGE, version=v).data, data
+    )
+    cluster.close()
+
+
 def test_death_schedules_background_repair():
     """The on_dead hook queues repair on the aux pool automatically."""
     cluster = Cluster(n_data_providers=4, page_replication=2,
@@ -482,10 +520,10 @@ def test_abandon_journal_replay_reconstructs_state():
     vm.abandon(blob, [v2])          # hole (v3 assigned after it)
     vm.report_success(blob, v3)
     v4, _ = vm.assign_version(blob, 0, 2)
-    vm.abandon(blob, [v4])          # tail erase: number reused
-    v4b, _ = vm.assign_version(blob, 12, 4)
-    assert v4b == v4
-    vm.report_success(blob, v4b)
+    vm.abandon(blob, [v4])          # tail hole: the number is never reused
+    v5, _ = vm.assign_version(blob, 12, 4)
+    assert v5 == v4 + 1
+    vm.report_success(blob, v5)
 
     recovered, orphans = VersionManager.recover(list(vm.journal))
     assert recovered.latest_published(blob) == vm.latest_published(blob)
@@ -895,6 +933,37 @@ def test_repair_skips_corrupt_survivor_as_source():
     cluster.close()
 
 
+# --------------------------- chaos campaign threads ----------------------------
+
+
+def _start_threads(targets, crashes):
+    """Start one daemon thread per ``(fn, *args)``; a thread that raises
+    appends its traceback to ``crashes`` instead of dying as a warning."""
+
+    def run(fn, *args):
+        try:
+            fn(*args)
+        except BaseException:
+            crashes.append(traceback.format_exc())
+
+    threads = [
+        threading.Thread(target=run, args=target, daemon=True)
+        for target in targets
+    ]
+    for t in threads:
+        t.start()
+    return threads
+
+
+def _join_threads(threads, crashes, timeout):
+    """Join ``threads``; fail on any still running or any that raised."""
+    for t in threads:
+        t.join(timeout)
+    stuck = [t.name for t in threads if t.is_alive()]
+    assert not stuck, f"campaign threads still running: {stuck}"
+    assert not crashes, "a campaign thread raised:\n" + "\n".join(crashes)
+
+
 # --------------------------- metadata chaos campaign ---------------------------
 
 
@@ -966,14 +1035,13 @@ def test_chaos_metadata_faults_zero_published_data_loss(seed):
                     errors.append(err)
             threading.Event().wait(0.002)
 
-    threads = [
-        threading.Thread(target=writer, args=(i, s))
-        for i, s in enumerate(writer_sessions)
-    ] + [threading.Thread(target=reader) for _ in range(2)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join(60)
+    crashes = []
+    threads = _start_threads(
+        [(writer, i, s) for i, s in enumerate(writer_sessions)]
+        + [(reader,)] * 2,
+        crashes,
+    )
+    _join_threads(threads, crashes, 60)
     assert not errors, f"reads failed under metadata chaos: {errors[:3]}"
 
     injector.drain()
@@ -1069,14 +1137,13 @@ def test_chaos_mixed_traffic_zero_published_data_loss(seed):
                     errors.append(err)
             threading.Event().wait(0.002)
 
-    threads = [
-        threading.Thread(target=writer, args=(i, s))
-        for i, s in enumerate(writer_sessions)
-    ] + [threading.Thread(target=reader) for _ in range(2)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join(60)
+    crashes = []
+    threads = _start_threads(
+        [(writer, i, s) for i, s in enumerate(writer_sessions)]
+        + [(reader,)] * 2,
+        crashes,
+    )
+    _join_threads(threads, crashes, 60)
     assert not errors, f"reads failed under chaos: {errors[:3]}"
 
     injector.drain()   # recover any provider still down
@@ -1129,7 +1196,8 @@ def test_chaos_node_faults_zero_published_data_loss(seed):
     traffic over one shared substrate while a seeded node-plane schedule
     kills / partitions / wedges whole nodes and a concurrent GC thread runs
     federated epoch/lease passes. Invariants (interleaving-independent):
-    zero published-data loss for versions GC was told to keep, a monotone
+    zero published-data loss for every version no GC pass was entitled to
+    drop (at or below the frontier that pass swept and not kept), a monotone
     publish frontier on every node, and the lease invariant — after the
     final pass no node's cache tier holds a collected version."""
     n_nodes, writers_per_node, readers_per_node = 4, 2, 2
@@ -1159,7 +1227,7 @@ def test_chaos_node_faults_zero_published_data_loss(seed):
     published = []  # (version, region, value) oracle, appended post-ack only
     published_lock = threading.Lock()
     errors = []
-    gc_floors = []  # keep-version of each mid-campaign GC pass
+    gc_passes = []  # (keep, frontier) of each mid-campaign GC pass
     stop_gc = threading.Event()
     n_rounds = 6
 
@@ -1206,24 +1274,23 @@ def test_chaos_node_faults_zero_published_data_loss(seed):
 
     def gc_loop():
         while not stop_gc.wait(0.02):
-            latest = fed.version_manager.latest_published(blob)
-            if latest:
-                fed.gc(blob, keep_versions=[latest])
-                gc_floors.append(latest)
+            keep = fed.version_manager.latest_published(blob)
+            if keep:
+                _, _, frontier = fed.gc(blob, keep_versions=[keep])
+                gc_passes.append((keep, frontier))
 
-    threads = (
-        [threading.Thread(target=writer, args=(n, s, sessions[n][s]))
+    crashes = []
+    traffic = _start_threads(
+        [(writer, n, s, sessions[n][s])
          for n in range(n_nodes) for s in range(writers_per_node)]
-        + [threading.Thread(target=reader, args=(n, sessions[n][writers_per_node + s]))
-           for n in range(n_nodes) for s in range(readers_per_node)]
-        + [threading.Thread(target=gc_loop)]
+        + [(reader, n, sessions[n][writers_per_node + s])
+           for n in range(n_nodes) for s in range(readers_per_node)],
+        crashes,
     )
-    for t in threads:
-        t.start()
-    for t in threads[:-1]:
-        t.join(120)
+    gc_thread = _start_threads([(gc_loop,)], crashes)
+    _join_threads(traffic, crashes, 120)
     stop_gc.set()
-    threads[-1].join(120)
+    _join_threads(gc_thread, crashes, 120)
     assert not errors, f"seed {seed}: stale/torn reads: {errors[:3]}"
 
     injector.drain()  # recover_all rejoins every downed node
@@ -1231,13 +1298,14 @@ def test_chaos_node_faults_zero_published_data_loss(seed):
     fed.repair_service.run_once()
     assert all(fed.node_mode(i) == "up" for i in range(n_nodes))
 
-    # -- zero published-data loss: every acked write GC never collected
-    floor = max(gc_floors, default=0)
+    # -- zero published-data loss: every acked write no GC pass was
+    #    entitled to collect (a pass keeps what it was asked to plus the
+    #    frontier it swept; every older published version may go)
     checker = fed.nodes[1].session(cache_bytes=0).open(blob)
     latest = checker.latest_published()
     for v, region, value in published:
-        if v < floor and v != latest:
-            continue  # collected by an explicit keep-latest GC pass
+        if any(v < frontier and v != keep for keep, frontier in gc_passes):
+            continue
         np.testing.assert_array_equal(
             checker.read(region * PAGE, 8 * PAGE, version=v).data,
             np.full(8 * PAGE, value, np.uint8),

@@ -271,10 +271,13 @@ def test_node_death_reclaims_lease_pins_and_recovers_writers():
     assert fed.coordinator.node_dead(1)
     assert fed.coordinator.pinned_versions(h0.blob_id) == set()
     assert not fed.coordinator.lease_valid(1)
-    # the dead writer's version was withdrawn: the next writer reuses the
-    # slot and the frontier advances straight through it
+    # the dead writer's version became a hole: its number is never
+    # reused, a late report from the writer fails, and the frontier
+    # advances straight past it
+    with pytest.raises(VersionAbandoned):
+        vm.report_success(h0.blob_id, doomed)
     v_next = h0.write(fill(3), 0)
-    assert v_next == doomed
+    assert v_next == doomed + 1
     assert vm.latest_published(h0.blob_id) == v_next
 
     fed.apply_node_fault(1, "recover")
@@ -295,6 +298,44 @@ def test_report_success_after_writer_recovery_raises_not_silent_loss():
     vm.abandon(blob, [v])  # writer recovery runs while the writer is mid-put
     with pytest.raises(VersionAbandoned):
         vm.report_success(blob, v)
+
+
+def test_abandoned_tail_version_number_is_never_reused():
+    """Writer recovery may abandon the newest assignment of a writer that is
+    still live; reissuing its number would let that writer's late report
+    publish the next owner's version. The number stays a hole instead."""
+    vm = VersionManager()
+    blob = vm.alloc(PAGES, PAGE)
+    v, _ = vm.assign_version(blob, 0, PAGES)
+    vm.abandon(blob, [v])
+    w, _ = vm.assign_version(blob, 0, PAGES)
+    assert w == v + 1
+    with pytest.raises(VersionAbandoned):
+        vm.report_success(blob, v)  # the live writer's late report
+    assert vm.latest_published(blob) == 0
+    vm.report_success(blob, w)
+    assert vm.latest_published(blob) == w
+
+
+def test_snapshot_that_lost_its_pin_to_a_death_verdict_fails_cleanly():
+    """A death verdict reclaims the node's pins, so a pass may collect a
+    version the node still holds a snapshot of. Reading that snapshot after
+    the node rejoins fails as a node fault (``ProviderFailed``), not as the
+    missing metadata that signals lost data."""
+    clock = FakeClock()
+    fed = make_fed(clock, dead_after=1)
+    h0 = fed.nodes[0].session().create(PAGE * PAGES, PAGE)
+    v1 = h0.write(fill(1), 0)
+    snap = fed.nodes[1].session(cache_bytes=0).open(h0.blob_id).at(v1)
+    v2 = h0.write(fill(2), 0)
+    fed.apply_node_fault(1, "partition")
+    fed.gc(h0.blob_id, keep_versions=[v2])  # death verdict, v1 collected
+    assert fed.coordinator.node_dead(1)
+    fed.apply_node_fault(1, "recover")
+    with pytest.raises(ProviderFailed):
+        snap.read(0, PAGE * PAGES)
+    snap.release()
+    fed.close()
 
 
 # ------------------------- version-abandon wakeups -----------------------------
@@ -325,7 +366,7 @@ def test_abandon_wakes_waiters_fail_fast():
     assert not t.is_alive()
     assert results == ["abandoned"]
 
-    # the erase case wakes waiters identically (withdrawn, not a hole)
+    # abandoning the newest assignment fails its waiters the same way
     vm.abandon(blob, [v2])
     with pytest.raises(VersionAbandoned):
         vm.wait_published(blob, v2, timeout=30.0)
@@ -343,19 +384,18 @@ def test_version_watch_skips_holes_and_waits_for_reissued_slots():
     assert watch.next(timeout=5.0) == v2
 
     v3, _ = vm.assign_version(blob, 0, PAGES)
-    vm.abandon(blob, [v3])  # erased: the slot number will be reissued
+    vm.abandon(blob, [v3])  # the newest assignment: a hole, never reused
     got = []
     t = threading.Thread(target=lambda: got.append(watch.next(timeout=30.0)))
     t.start()
     threading.Event().wait(0.05)
-    # the watch must NOT have consumed the erased slot: when the number is
-    # reissued and published, it is delivered
-    v3_again, _ = vm.assign_version(blob, 0, PAGES)
-    assert v3_again == v3
-    vm.report_success(blob, v3_again)
+    # the watch steps over the tail hole and waits for the next number
+    v4, _ = vm.assign_version(blob, 0, PAGES)
+    assert v4 == v3 + 1
+    vm.report_success(blob, v4)
     t.join(5.0)
     assert not t.is_alive()
-    assert got == [v3]
+    assert got == [v4]
 
 
 # ------------------------------ coordinator unit -------------------------------
@@ -379,28 +419,80 @@ def test_coordinator_lease_and_epoch_protocol():
     assert not coord.renew(0)  # expired leases cannot be renewed
 
 
+def _on_first_metadata_rpc(fed, thread, action):
+    """Run ``action`` once, inside the first metadata RPC ``thread`` makes:
+    for a thread running ``fed.gc`` that is the home node's sweep marking
+    its first tree. Returns an event set when it fired."""
+    fired = threading.Event()
+
+    def gate(op, shard_id):
+        if threading.current_thread() is thread and not fired.is_set():
+            fired.set()
+            action()
+
+    for shard in fed.metadata.shards:
+        shard.fault_gate = gate
+    return fired
+
+
 def test_coordinator_pins_block_during_sweep():
+    """A pin on any node (resolve + register at the coordinator) waits out
+    the home node's sweep: every node shares the home node's gc guard, so
+    the pin lands strictly after the pass and its version stays readable."""
     clock = FakeClock()
-    coord = GcEpochCoordinator(lease_seconds=10.0, clock=clock)
-    coord.join(0)
-    coord.pin(0, blob_id=7, version=3)
-    assert coord.begin_sweep(7) == {3}
-    landed = threading.Event()
+    fed = make_fed(clock)
+    h0 = fed.nodes[0].session().create(PAGE * PAGES, PAGE)
+    h0.write(fill(1), 0)
+    h1 = fed.nodes[1].session().open(h0.blob_id)
+    release = threading.Event()
+    gc_thread = threading.Thread(
+        target=fed.gc, args=(h0.blob_id, [1]), daemon=True
+    )
+    in_sweep = _on_first_metadata_rpc(
+        fed, gc_thread, lambda: release.wait(10.0)
+    )
+    gc_thread.start()
+    assert in_sweep.wait(10.0)
+    snaps = []
+    pinner = threading.Thread(
+        target=lambda: snaps.append(h1.at(None)), daemon=True
+    )
+    pinner.start()
+    pinner.join(0.1)
+    assert pinner.is_alive()  # parked while sweeping
+    assert fed.coordinator.pinned_versions(h0.blob_id) == set()
+    release.set()
+    gc_thread.join(10.0)
+    pinner.join(10.0)
+    assert not gc_thread.is_alive() and not pinner.is_alive()
+    assert fed.coordinator.pinned_versions(h0.blob_id) == {1}
+    np.testing.assert_array_equal(snaps[0].read(0, PAGE * PAGES), fill(1))
+    snaps[0].release()
+    assert fed.coordinator.pinned_versions(h0.blob_id) == set()
+    fed.close()
 
-    def late_pinner():
-        coord.pin(0, blob_id=7, version=4)  # must wait out the sweep
-        landed.set()
 
-    t = threading.Thread(target=late_pinner)
-    t.start()
-    assert not landed.wait(0.1)  # parked while sweeping
-    coord.end_sweep()
-    assert landed.wait(5.0)
-    t.join(5.0)
-    assert coord.pinned_versions(7) == {3, 4}
-    coord.unpin(0, 7, 3)
-    coord.unpin(0, 7, 4)
-    assert coord.pinned_versions(7) == set()
+def test_rejoin_inside_the_sweep_does_not_deadlock():
+    """Regression: a chaos harness applies a due node ``recover`` on the
+    thread whose RPC reaches it — here the GC thread, inside its own sweep.
+    The rejoin's pin resync must not wait for that sweep to close."""
+    clock = FakeClock()
+    fed = make_fed(clock)
+    h0 = fed.nodes[0].session().create(PAGE * PAGES, PAGE)
+    v1 = h0.write(fill(1), 0)
+    fed.apply_node_fault(1, "kill")
+    gc_thread = threading.Thread(
+        target=fed.gc, args=(h0.blob_id, [v1]), daemon=True
+    )
+    rejoined = _on_first_metadata_rpc(
+        fed, gc_thread, lambda: fed.rejoin_node(1)
+    )
+    gc_thread.start()
+    gc_thread.join(10.0)
+    assert rejoined.is_set()
+    assert not gc_thread.is_alive(), "GC pass wedged on its own rejoin"
+    assert fed.node_mode(1) == "up"
+    fed.close()
 
 
 def test_coordinator_death_is_sticky_until_revive():

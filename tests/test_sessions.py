@@ -49,16 +49,34 @@ def test_snapshot_pin_survives_gc_of_other_versions():
     handle.write(page(1, 8 * PAGE), 0)  # v1
     snap = handle.at(1)
     handle.write(page(2, 8 * PAGE), 0)  # v2 rewrites everything
-    nodes, pages = cluster.gc(handle.blob_id, keep_versions=[2])
+    nodes, pages, _ = cluster.gc(handle.blob_id, keep_versions=[2])
     assert (nodes, pages) == (0, 0)  # v1 was pinned: nothing collectable
     assert (snap.read(0, 8 * PAGE) == 1).all()
     # releasing the pin makes v1 collectable
     snap.release()
     assert not snap.pinned
-    nodes, pages = cluster.gc(handle.blob_id, keep_versions=[2])
+    nodes, pages, _ = cluster.gc(handle.blob_id, keep_versions=[2])
     assert pages == 8  # v1's pages die now
     with pytest.raises(KeyError):
         handle.read(0, 8 * PAGE, version=1)
+    cluster.close()
+
+
+def test_gc_tolerates_a_pin_on_an_already_collected_version():
+    """A snapshot of a version an earlier pass collected protects nothing
+    (its first read fails), but its pin must not break later GC passes."""
+    cluster = make_cluster()
+    handle = cluster.session().create(8 * PAGE, PAGE)
+    handle.write(page(1, 8 * PAGE), 0)  # v1
+    handle.write(page(2, 8 * PAGE), 0)  # v2 rewrites everything
+    cluster.gc(handle.blob_id, keep_versions=[2])  # v1 collected
+    snap = handle.at(1)
+    handle.write(page(3, 8 * PAGE), 0)  # v3
+    _, pages, frontier = cluster.gc(handle.blob_id, keep_versions=[3])
+    assert (pages, frontier) == (8, 3)  # v2's pages die; v1's pin is moot
+    with pytest.raises(KeyError):
+        snap.read(0, 8 * PAGE)
+    snap.release()
     cluster.close()
 
 
@@ -403,7 +421,7 @@ def test_blobstore_facade_smoke():
     assert store.page_cache is not None and store.replica_balancer is not None
     assert store.stats.data_rounds > 0
     assert store.storage_bytes() > 0
-    nodes, pages = store.gc(blob, keep_versions=[v5])
+    _, pages, _ = store.gc(blob, keep_versions=[v5])
     assert pages > 0
     assert (store.read(blob, None, 0, PAGE).data[:1] == 1).all()
     store.close()

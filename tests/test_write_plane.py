@@ -401,13 +401,15 @@ def test_failed_writev_releases_placements_and_deletes_orphans():
     )
     # metadata nodes of the doomed version dropped
     assert cluster.metadata.total_nodes() == 0
-    # the assigned version was withdrawn: nothing wedges, number is reused
-    assert cluster.version_manager.assigned_versions(handle.blob_id) == 0
+    # the assigned version was withdrawn as a hole: nothing wedges, and the
+    # number is never reused
+    assert cluster.version_manager.assigned_versions(handle.blob_id) == 1
+    assert cluster.version_manager.is_aborted(handle.blob_id, 1)
     dropping[0] = False
     cluster.provider_manager.recover_provider(0)  # rejoin: live + placeable
     v = handle.write(page(2, 4 * PAGE), 0)
-    assert v == 1
-    assert handle.latest_published() == 1
+    assert v == 2
+    assert handle.latest_published() == 2
     np.testing.assert_array_equal(
         handle.read(0, 4 * PAGE).data, page(2, 4 * PAGE)
     )
@@ -426,12 +428,15 @@ def test_abandon_hole_is_skipped_and_rejected():
     assert vm.latest_published(blob) == 2
     with pytest.raises(ValueError, match="abandoned"):
         vm.resolve_read_version(blob, v1)
-    # the tail case fully erases: numbers are reused
+    # the tail case is a hole too: its number is never reused, so a late
+    # report from its (possibly live) writer cannot land on a new owner
     v3, _ = vm.assign_version(blob, 0, 1)
     vm.abandon(blob, [v3])
-    assert vm.assigned_versions(blob) == 2
-    v3b, _ = vm.assign_version(blob, 2, 1)
-    assert v3b == v3
+    assert vm.assigned_versions(blob) == 3
+    with pytest.raises(ValueError, match="abandoned"):
+        vm.resolve_read_version(blob, v3)
+    v4, _ = vm.assign_version(blob, 2, 1)
+    assert v4 == v3 + 1
 
 
 def test_recover_replays_abandon_entries():
