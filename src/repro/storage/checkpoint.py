@@ -17,7 +17,10 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import json
+import os
 import threading
+import time
+from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -42,6 +45,75 @@ class CheckpointRecord:
     version: int  # blob version at which this checkpoint is complete
     dirty_pages: int
     total_pages: int
+    workers: int = 1  # threads that hashed the pages; 1: on the saving thread
+    host_wait_s: float = 0.0  # seconds blocked on device-to-host copies
+
+
+#: bytes of a leaf one save task hashes: 1-MiB pages go 8 to a task, 4-KiB
+#: pages 2048
+_TASK_BYTES = 8 << 20
+
+
+def _copy_c_order(dst: np.ndarray, src: np.ndarray, start: int, stop: int) -> None:
+    """Copy elements ``start:stop`` of ``src``'s C-order flattening to the
+    same places of ``dst``, a 1-d array of ``src.size`` elements: whole
+    slabs along the first axis in one copy each, a slab cut by either end
+    recursively."""
+    if src.ndim <= 1:
+        dst[start:stop] = src.reshape(-1)[start:stop]
+        return
+    row = src[0].size
+    lo, hi = -(-start // row), stop // row  # the slabs wholly inside
+    if lo > hi:  # inside one slab
+        r = start // row
+        _copy_c_order(dst[r * row : (r + 1) * row], src[r], start - r * row, stop - r * row)
+        return
+    if start < lo * row:
+        _copy_c_order(dst[(lo - 1) * row : lo * row], src[lo - 1], start - (lo - 1) * row, row)
+    if lo < hi:
+        dst[lo * row : hi * row].reshape(src[lo:hi].shape)[...] = src[lo:hi]
+    if stop > hi * row:
+        _copy_c_order(dst[hi * row : (hi + 1) * row], src[hi], 0, stop - hi * row)
+
+
+def _hash_pages(
+    arr: np.ndarray, stage: np.ndarray, offset: int, ps: int, lo: int, hi: int,
+    old: List[Optional[bytes]],
+) -> List[bytes]:
+    """Digest pages ``lo:hi`` of one leaf's C-order bytes (its last page
+    zero-padded); ``stage`` at the leaf's ``offset`` ends up holding every
+    page whose digest is not its ``old`` one, zero-padded.
+
+    A C-contiguous leaf is read in place and only those pages are copied.
+    Any other leaf (a device array can come back in its device layout) has
+    the pages copied into ``stage`` in C order first, and read there."""
+    own = stage[offset : offset + arr.nbytes]
+    if arr.flags.c_contiguous:
+        flat = arr.reshape(-1).view(np.uint8)
+    else:
+        isz = arr.itemsize
+        _copy_c_order(own.view(arr.dtype), arr, lo * ps // isz, min(-(-hi * ps // isz), arr.size))
+        flat = own
+    digests = []
+    for p, prev in zip(range(lo, hi), old):
+        page = flat[p * ps : (p + 1) * ps]
+        h = hashlib.blake2b(page, digest_size=16)
+        if page.size < ps:
+            h.update(bytes(ps - page.size))
+        digest = h.digest()
+        if digest != prev:
+            dst = stage[offset + p * ps : offset + (p + 1) * ps]
+            if flat is not own:
+                dst[: page.size] = page
+            dst[page.size :] = 0
+        digests.append(digest)
+    return digests
+
+
+def _run_inline(fn, *args) -> Future:
+    done: Future = Future()
+    done.set_result(fn(*args))
+    return done
 
 
 def _leaf_paths(tree) -> List[Tuple[str, Any]]:
@@ -69,6 +141,7 @@ class BlobCheckpointer:
             size = int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize if leaf.shape else np.dtype(leaf.dtype).itemsize
             self.layout.append(LeafInfo(path, off, size, str(leaf.dtype), tuple(leaf.shape)))
             off += -(-size // page_size) * page_size  # page-align every leaf
+        self._span = off
         total = max(off, page_size)
         # blob sizes are powers of two (paper §II)
         self.blob_bytes = 1 << (total - 1).bit_length()
@@ -81,55 +154,80 @@ class BlobCheckpointer:
 
     # -- save -------------------------------------------------------------------------
     def save(self, step: int, state: Any) -> CheckpointRecord:
-        """Write dirty pages of ``state``; returns the checkpoint record."""
+        """Write dirty pages of ``state``; returns the checkpoint record.
+
+        Every device leaf's copy to the host starts first; leaf by leaf, as
+        each lands, a pool of threads hashes its pages and fills one staging
+        buffer with the dirty ones, while later leaves are still in flight.
+        Runs of dirty pages (a run ends at a clean page and at a leaf's end)
+        are written from views of that buffer, one ``write`` each.
+        """
         with self._lock:
-            leaves = _leaf_paths(state)
-            assert len(leaves) == len(self.layout), "state structure changed"
-            dirty_runs: List[Tuple[int, bytes]] = []  # (page_index, page_bytes...)
-            dirty = 0
-            total_pages_touched = 0
+            leaves = [leaf for _, leaf in _leaf_paths(state)]
+            if len(leaves) != len(self.layout):
+                raise ValueError("state structure changed")
+            for leaf in leaves:
+                if isinstance(leaf, jax.Array):
+                    leaf.copy_to_host_async()
             ps = self.page_size
+            per_task = max(1, _TASK_BYTES // ps)
+            n_tasks = sum(-(-info.size // (per_task * ps)) for info in self.layout)
+            workers = max(1, min(n_tasks, len(os.sched_getaffinity(0))))
+            # the only host copy the save makes: dirty pages land here (every
+            # page of a leaf that is not C-contiguous), and the store keeps
+            # views of it, so it is fresh for every save
+            stage = np.empty(self._span, np.uint8)
+            pool = ThreadPoolExecutor(workers) if workers > 1 else None
+            submit = pool.submit if pool else _run_inline
+            hashed: List[Tuple[int, List[Future]]] = []  # (first page, tasks) a leaf
+            host_wait = 0.0
+            try:
+                for info, leaf in zip(self.layout, leaves):
+                    t0 = time.perf_counter()
+                    arr = np.asarray(leaf)
+                    host_wait += time.perf_counter() - t0
+                    if arr.nbytes != info.size:
+                        raise ValueError(f"leaf {info.path} changed size")
+                    first = info.offset // ps
+                    n_pages = -(-info.size // ps)
+                    tasks = []
+                    for lo in range(0, n_pages, per_task):
+                        hi = min(lo + per_task, n_pages)
+                        old = [self._page_hash.get(first + p) for p in range(lo, hi)]
+                        tasks.append(submit(_hash_pages, arr, stage, info.offset, ps, lo, hi, old))
+                    hashed.append((first, tasks))
 
-            run_start: Optional[int] = None
-            run_chunks: List[bytes] = []
+                # gather in page order on this thread
+                fresh: Dict[int, bytes] = {}
+                runs: List[List[int]] = []  # [first page, pages] of each dirty run
+                total_pages = 0
+                for first, tasks in hashed:
+                    run: Optional[List[int]] = None
+                    page = first
+                    for task in tasks:
+                        for digest in task.result():
+                            if self._page_hash.get(page) == digest:
+                                run = None
+                            else:
+                                fresh[page] = digest
+                                if run is None:
+                                    run = [page, 0]
+                                    runs.append(run)
+                                run[1] += 1
+                            page += 1
+                    total_pages += page - first
+            finally:
+                if pool:
+                    pool.shutdown(cancel_futures=True)
 
-            def flush_run():
-                nonlocal run_start, run_chunks
-                if run_start is not None:
-                    dirty_runs.append((run_start, b"".join(run_chunks)))
-                run_start, run_chunks = None, []
-
-            for info, (path, leaf) in zip(self.layout, leaves):
-                arr = np.ascontiguousarray(jax.device_get(leaf))
-                raw = arr.tobytes()
-                n_pages = -(-len(raw) // ps)
-                total_pages_touched += n_pages
-                first_page = info.offset // ps
-                for p in range(n_pages):
-                    chunk = raw[p * ps : (p + 1) * ps]
-                    if len(chunk) < ps:
-                        chunk = chunk + b"\0" * (ps - len(chunk))
-                    h = hashlib.blake2b(chunk, digest_size=16).digest()
-                    page_idx = first_page + p
-                    if self._page_hash.get(page_idx) == h:
-                        flush_run()
-                        continue
-                    self._page_hash[page_idx] = h
-                    dirty += 1
-                    if run_start is None:
-                        run_start = page_idx
-                    elif run_start + len(run_chunks) != page_idx:
-                        flush_run()
-                        run_start = page_idx
-                    run_chunks.append(chunk)
-                flush_run()
-
+            # the store takes read-only views without a copy of its own
+            stage.flags.writeable = False
             version = self.handle.latest_published()
-            for page_idx, data in dirty_runs:
-                buf = np.frombuffer(data, dtype=np.uint8)
-                version = self.handle.write(buf, page_idx * ps)
+            for page_idx, n in runs:
+                version = self.handle.write(stage[page_idx * ps : (page_idx + n) * ps], page_idx * ps)
+            self._page_hash.update(fresh)
 
-            rec = CheckpointRecord(step, version, dirty, total_pages_touched)
+            rec = CheckpointRecord(step, version, len(fresh), total_pages, workers, host_wait)
             self.checkpoints.append(rec)
             self._gc()
             return rec

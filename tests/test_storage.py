@@ -1,6 +1,10 @@
 """Tests for the paged-KV allocator (prefix sharing / COW) and the
 incremental blob checkpointer."""
 
+import hashlib
+import json
+import os
+import sys
 import threading
 
 import jax
@@ -9,7 +13,7 @@ import numpy as np
 import pytest
 
 from repro.core import Cluster
-from repro.storage.checkpoint import BlobCheckpointer
+from repro.storage.checkpoint import BlobCheckpointer, _copy_c_order
 from repro.storage.kvcache import PagedKVAllocator
 
 
@@ -212,3 +216,199 @@ def test_checkpoint_reshard_restore():
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), state)
     out = ck.restore(0, shardings=sh)
     assert all(x.sharding == NamedSharding(mesh, P()) for x in jax.tree.leaves(out))
+
+# ---------------------------- the streamed save ----------------------------
+def _strided(shape=(23, 100, 1001), perm=(1, 0, 2)):
+    """A NumPy leaf that is not C-contiguous, as a device array can come back
+    in its device layout: the axes of a C-order array permuted."""
+    base = np.arange(np.prod(shape), dtype=np.float32).reshape(shape)
+    return base.transpose(perm)
+
+
+def _odd_state():
+    """Leaves whose sizes are not page multiples, a 0-d one and NumPy ones
+    among them; ``big`` and ``strided`` (not C-contiguous) span two save tasks
+    at either page size."""
+    k1, k2 = jax.random.split(jax.random.PRNGKey(7))
+    return {
+        "big": jax.random.normal(k1, (2_200_001,), jnp.float32),
+        "mat": jax.random.randint(k2, (37, 29), -1000, 1000, jnp.int32),
+        "np": np.linspace(-1.0, 1.0, 1001, dtype=np.float32),
+        "scalar": jnp.array(3.5, jnp.float32),
+        "strided": _strided(),
+        "tail": jnp.arange(5, dtype=jnp.int32),
+    }
+
+
+def _serial_page_hashes(ck, state):
+    """The save's digests, computed plainly: each leaf's bytes cut into pages,
+    the last zero-padded, blake2b with 16-byte digests."""
+    ps = ck.page_size
+    out = {}
+    for info, leaf in zip(ck.layout, jax.tree.leaves(state)):
+        raw = np.asarray(leaf).tobytes()
+        for p in range(-(-len(raw) // ps)):
+            page = raw[p * ps : (p + 1) * ps].ljust(ps, b"\0")
+            out[info.offset // ps + p] = hashlib.blake2b(page, digest_size=16).digest()
+    return out
+
+
+def _assert_restores(ck, step, state):
+    out = ck.restore(step)
+    for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(state)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert a.tobytes() == b.tobytes()
+
+
+def _count_writes(ck):
+    """Wrap the handle's ``write`` on the instance, as the benchmark's span
+    does; returns the list of (offset, bytes) of every call."""
+    calls = []
+    write = ck.handle.write
+
+    def counted(buf, offset):
+        calls.append((offset, buf.nbytes))
+        return write(buf, offset)
+
+    ck.handle.write = counted
+    return calls
+
+
+@pytest.mark.parametrize("page_size", [4096, 65536])
+def test_streamed_save_matches_serial_reference(page_size):
+    state = _odd_state()
+    ck = BlobCheckpointer(make_session(), state, page_size=page_size)
+    calls = _count_writes(ck)
+    rec = ck.save(0, state)
+    ref = _serial_page_hashes(ck, state)
+    assert ck._page_hash == ref
+    assert rec.dirty_pages == rec.total_pages == len(ref)
+    # every page dirty: one run, so one write, per leaf
+    assert [off for off, _ in calls] == [info.offset for info in ck.layout]
+    _assert_restores(ck, 0, state)
+    # stored bytes: each leaf's, its last page zero-padded
+    for info, leaf in zip(ck.layout, jax.tree.leaves(state)):
+        span = -(-info.size // page_size) * page_size
+        stored = ck.handle.read(info.offset, span, version=rec.version).data.tobytes()
+        assert stored == np.asarray(leaf).tobytes().ljust(span, b"\0")
+    # the same state again: nothing dirty, nothing written
+    rec = ck.save(1, state)
+    assert (rec.dirty_pages, rec.total_pages) == (0, len(ref))
+    assert len(calls) == len(ck.layout)
+    _assert_restores(ck, 1, state)
+
+
+@pytest.mark.parametrize("page_size", [4096, 65536])
+def test_streamed_save_writes_one_call_per_dirty_run(page_size):
+    state = _odd_state()
+    ck = BlobCheckpointer(make_session(), state, page_size=page_size)
+    ck.save(0, state)
+    calls = _count_writes(ck)
+    per_page = page_size // 4
+    big = np.array(state["big"])
+    for p in (1, 3, 5, 6):  # runs: [1], [3], [5, 6]
+        big[p * per_page] += 1.0
+    mat = np.array(state["mat"])
+    mat[0, 0] += 1
+    strided = state["strided"].copy(order="K")  # keeps the permuted layout
+    for p in (0, 2):  # the C-order element that starts page p: runs [0], [2]
+        strided[np.unravel_index(p * per_page, strided.shape)] += 1.0
+    state2 = dict(state, big=jnp.asarray(big), mat=jnp.asarray(mat), strided=strided)
+    rec = ck.save(1, state2)
+    assert ck._page_hash == _serial_page_hashes(ck, state2)
+    first = {info.path: info.offset for info in ck.layout}
+    b, m, s = first["['big']"], first["['mat']"], first["['strided']"]
+    assert calls == [
+        (b + page_size, page_size),
+        (b + 3 * page_size, page_size),
+        (b + 5 * page_size, 2 * page_size),
+        (m, page_size),
+        (s, page_size),
+        (s + 2 * page_size, page_size),
+    ]
+    assert rec.dirty_pages == 7
+    _assert_restores(ck, 0, state)
+    _assert_restores(ck, 1, state2)
+
+
+def test_streamed_save_never_aliases_caller_arrays():
+    x = np.arange(3000, dtype=np.float32)
+    y = np.full((17, 3), 2.0, np.float64)
+    ck = BlobCheckpointer(make_session(), {"x": x, "y": y}, page_size=4096)
+    ck.save(0, {"x": x, "y": y})
+    saved = {"x": x.copy(), "y": y.copy()}
+    assert x.flags.writeable and y.flags.writeable
+    x[:] = -1.0
+    y[0, 0] = 7.0
+    _assert_restores(ck, 0, saved)
+    ck.save(1, {"x": x, "y": y})
+    _assert_restores(ck, 1, {"x": x, "y": y})
+    _assert_restores(ck, 0, saved)
+
+
+def test_streamed_save_records_its_workers():
+    one = {"w": jnp.ones((100,), jnp.float32)}
+    ck = BlobCheckpointer(make_session(), one, page_size=4096)
+    rec = ck.save(0, one)
+    assert rec.workers == 1 and rec.host_wait_s >= 0
+    assert json.loads(ck.manifest())["checkpoints"][0]["workers"] == 1
+    if len(os.sched_getaffinity(0)) == 1:
+        pytest.skip("one core: the save never needs a pool")
+    many = {f"w{i}": jnp.full((100,), float(i), jnp.float32) for i in range(8)}
+    ck = BlobCheckpointer(make_session(), many, page_size=4096)
+    rec = ck.save(0, many)
+    assert rec.workers > 1 and rec.host_wait_s >= 0
+    _assert_restores(ck, 0, many)
+
+
+def test_streamed_save_refuses_a_resized_leaf():
+    state = _tiny_state()
+    ck = BlobCheckpointer(make_session(), state, page_size=4096)
+    ck.save(0, state)
+    hashes, version = dict(ck._page_hash), ck.handle.latest_published()
+    with pytest.raises(ValueError, match="w2"):
+        ck.save(1, dict(state, w2=jnp.zeros((33,), jnp.float32)))
+    assert ck._page_hash == hashes and ck.handle.latest_published() == version
+    assert [c.step for c in ck.checkpoints] == [0]
+
+
+@pytest.mark.parametrize(
+    "shape,perm",
+    [((5, 7, 9), (1, 0, 2)), ((4, 3, 6, 5), (0, 1, 3, 2)), ((6, 8), (1, 0)),
+     ((13,), (0,)), ((3, 1, 4), (2, 1, 0))],
+)
+def test_copy_c_order_copies_exactly_the_range(shape, perm):
+    rng = np.random.default_rng(len(shape))
+    src = rng.standard_normal(shape).astype(np.float32).transpose(perm)
+    ref = src.reshape(-1)  # a C-order copy
+    for start, stop in [(0, src.size), (0, 0), (src.size, src.size)] + [
+        tuple(sorted(rng.integers(0, src.size + 1, 2))) for _ in range(100)
+    ]:
+        dst = np.full(src.size, np.nan, np.float32)
+        _copy_c_order(dst, src, start, stop)
+        np.testing.assert_array_equal(dst[start:stop], ref[start:stop])
+        assert np.isnan(dst[:start]).all() and np.isnan(dst[stop:]).all()
+
+
+def test_streamed_save_under_thread_stress(monkeypatch):
+    """More save threads than cores and a short switch interval: every
+    save still matches the serial reference and restores exactly."""
+    import repro.storage.checkpoint as ckpt_mod
+
+    monkeypatch.setattr(ckpt_mod.os, "sched_getaffinity", lambda pid: set(range(64)))
+    rng = np.random.default_rng(3)
+    state = {f"l{i:02d}": rng.standard_normal(3000 + 17 * i).astype(np.float32) for i in range(24)}
+    state["strided"] = _strided((7, 300, 9), (1, 2, 0))
+    ck = BlobCheckpointer(make_session(), state, page_size=4096)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for step in range(4):
+            rec = ck.save(step, state)
+            assert rec.workers == len(ck.layout)
+            assert ck._page_hash == _serial_page_hashes(ck, state)
+            _assert_restores(ck, step, state)
+            state = {k: (v + 1 if rng.random() < 0.5 else v) for k, v in state.items()}
+    finally:
+        sys.setswitchinterval(interval)
