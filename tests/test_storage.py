@@ -276,7 +276,18 @@ def _count_writes(ck):
 
 
 @pytest.mark.parametrize("page_size", [4096, 65536])
-def test_streamed_save_matches_serial_reference(page_size):
+def test_streamed_save_matches_serial_reference(page_size, monkeypatch):
+    # a fresh np.empty may hand back used memory; make every uint8 one do so,
+    # so that padding the save leaves unzeroed shows in the stored bytes
+    empty = np.empty
+
+    def used_empty(*args, **kwargs):
+        out = empty(*args, **kwargs)
+        if out.dtype == np.uint8:
+            out.fill(0xAB)
+        return out
+
+    monkeypatch.setattr(np, "empty", used_empty)
     state = _odd_state()
     ck = BlobCheckpointer(make_session(), state, page_size=page_size)
     calls = _count_writes(ck)
