@@ -45,7 +45,7 @@ class CheckpointRecord:
     version: int  # blob version at which this checkpoint is complete
     dirty_pages: int
     total_pages: int
-    workers: int = 1  # threads that hashed the pages; 1: on the saving thread
+    workers: int = 1  # threads that hashed the pages
     host_wait_s: float = 0.0  # seconds blocked on device-to-host copies
 
 
@@ -79,10 +79,11 @@ def _copy_c_order(dst: np.ndarray, src: np.ndarray, start: int, stop: int) -> No
 def _hash_pages(
     arr: np.ndarray, stage: np.ndarray, offset: int, ps: int, lo: int, hi: int,
     old: List[Optional[bytes]],
-) -> List[bytes]:
+) -> List[Optional[bytes]]:
     """Digest pages ``lo:hi`` of one leaf's C-order bytes (its last page
-    zero-padded); ``stage`` at the leaf's ``offset`` ends up holding every
-    page whose digest is not its ``old`` one, zero-padded.
+    zero-padded): the digest of each page whose digest is not its ``old``
+    one, and ``None`` for each page that is clean. ``stage`` at the leaf's
+    ``offset`` ends up holding every dirty page, zero-padded.
 
     A C-contiguous leaf is read in place and only those pages are copied.
     Any other leaf (a device array can come back in its device layout) has
@@ -94,26 +95,22 @@ def _hash_pages(
         isz = arr.itemsize
         _copy_c_order(own.view(arr.dtype), arr, lo * ps // isz, min(-(-hi * ps // isz), arr.size))
         flat = own
-    digests = []
+    digests: List[Optional[bytes]] = []
     for p, prev in zip(range(lo, hi), old):
         page = flat[p * ps : (p + 1) * ps]
         h = hashlib.blake2b(page, digest_size=16)
         if page.size < ps:
             h.update(bytes(ps - page.size))
         digest = h.digest()
-        if digest != prev:
-            dst = stage[offset + p * ps : offset + (p + 1) * ps]
-            if flat is not own:
-                dst[: page.size] = page
-            dst[page.size :] = 0
+        if digest == prev:
+            digests.append(None)
+            continue
+        dst = stage[offset + p * ps : offset + (p + 1) * ps]
+        if flat is not own:
+            dst[: page.size] = page
+        dst[page.size :] = 0
         digests.append(digest)
     return digests
-
-
-def _run_inline(fn, *args) -> Future:
-    done: Future = Future()
-    done.set_result(fn(*args))
-    return done
 
 
 def _leaf_paths(tree) -> List[Tuple[str, Any]]:
@@ -160,7 +157,7 @@ class BlobCheckpointer:
         each lands, a pool of threads hashes its pages and fills one staging
         buffer with the dirty ones, while later leaves are still in flight.
         Runs of dirty pages (a run ends at a clean page and at a leaf's end)
-        are written from views of that buffer, one ``write`` each.
+        are written from that buffer, one ``write`` each.
         """
         with self._lock:
             leaves = [leaf for _, leaf in _leaf_paths(state)]
@@ -173,12 +170,10 @@ class BlobCheckpointer:
             per_task = max(1, _TASK_BYTES // ps)
             n_tasks = sum(-(-info.size // (per_task * ps)) for info in self.layout)
             workers = max(1, min(n_tasks, len(os.sched_getaffinity(0))))
-            # the only host copy the save makes: dirty pages land here (every
-            # page of a leaf that is not C-contiguous), and the store keeps
-            # views of it, so it is fresh for every save
+            # the one staging copy: dirty pages land here (every page of a
+            # leaf that is not C-contiguous, for hashing in C order)
             stage = np.empty(self._span, np.uint8)
-            pool = ThreadPoolExecutor(workers) if workers > 1 else None
-            submit = pool.submit if pool else _run_inline
+            pool = ThreadPoolExecutor(workers)
             hashed: List[Tuple[int, List[Future]]] = []  # (first page, tasks) a leaf
             host_wait = 0.0
             try:
@@ -194,7 +189,7 @@ class BlobCheckpointer:
                     for lo in range(0, n_pages, per_task):
                         hi = min(lo + per_task, n_pages)
                         old = [self._page_hash.get(first + p) for p in range(lo, hi)]
-                        tasks.append(submit(_hash_pages, arr, stage, info.offset, ps, lo, hi, old))
+                        tasks.append(pool.submit(_hash_pages, arr, stage, info.offset, ps, lo, hi, old))
                     hashed.append((first, tasks))
 
                 # gather in page order on this thread
@@ -206,7 +201,7 @@ class BlobCheckpointer:
                     page = first
                     for task in tasks:
                         for digest in task.result():
-                            if self._page_hash.get(page) == digest:
+                            if digest is None:
                                 run = None
                             else:
                                 fresh[page] = digest
@@ -217,14 +212,18 @@ class BlobCheckpointer:
                             page += 1
                     total_pages += page - first
             finally:
-                if pool:
-                    pool.shutdown(cancel_futures=True)
+                pool.shutdown(cancel_futures=True)
 
-            # the store takes read-only views without a copy of its own
+            # the store keeps what it is given until the pages are superseded:
+            # read-only views of the staging buffer when the runs tile it (every
+            # page dirty), else a copy of each run, so that it keeps only the
+            # dirty bytes
             stage.flags.writeable = False
+            tiled = len(fresh) == total_pages
             version = self.handle.latest_published()
             for page_idx, n in runs:
-                version = self.handle.write(stage[page_idx * ps : (page_idx + n) * ps], page_idx * ps)
+                buf = stage[page_idx * ps : (page_idx + n) * ps]
+                version = self.handle.write(buf if tiled else buf.copy(), page_idx * ps)
             self._page_hash.update(fresh)
 
             rec = CheckpointRecord(step, version, len(fresh), total_pages, workers, host_wait)

@@ -343,6 +343,47 @@ def test_streamed_save_writes_one_call_per_dirty_run(page_size):
     _assert_restores(ck, 1, state2)
 
 
+def _root(arr):
+    while isinstance(arr.base, np.ndarray):
+        arr = arr.base
+    return arr
+
+
+@pytest.mark.parametrize("page_size", [4096, 65536])
+def test_partial_save_hands_the_store_only_the_dirty_bytes(page_size):
+    """The store keeps what a save hands it while the pages are current: a
+    partial save must not hand it views that keep a whole staging buffer
+    alive. One page of a C-contiguous leaf and one of a permuted leaf (every
+    page of which is staged for hashing) are dirty."""
+    state = _odd_state()
+    ck = BlobCheckpointer(make_session(), state, page_size=page_size)
+    ck.save(0, state)
+    given = []
+    write = ck.handle.write
+
+    def keep(buf, offset):
+        given.append(buf)
+        return write(buf, offset)
+
+    ck.handle.write = keep
+    providers = list(ck.session.cluster.provider_manager._providers.values())
+    before = {(p.provider_id, key) for p in providers for key in p._pages}
+    big = np.array(state["big"])
+    big[page_size // 4] += 1.0
+    strided = state["strided"].copy(order="K")
+    strided[np.unravel_index(0, strided.shape)] += 1.0
+    state2 = dict(state, big=jnp.asarray(big), strided=strided)
+    rec = ck.save(1, state2)
+    assert rec.dirty_pages == 2 and len(given) == 2
+    stored = [page for p in providers for key, page in p._pages.items()
+              if (p.provider_id, key) not in before]
+    assert len(stored) >= 2
+    for page in given + stored:
+        assert _root(page).nbytes <= page_size
+    _assert_restores(ck, 0, state)
+    _assert_restores(ck, 1, state2)
+
+
 def test_streamed_save_never_aliases_caller_arrays():
     x = np.arange(3000, dtype=np.float32)
     y = np.full((17, 3), 2.0, np.float64)
@@ -365,7 +406,7 @@ def test_streamed_save_records_its_workers():
     assert rec.workers == 1 and rec.host_wait_s >= 0
     assert json.loads(ck.manifest())["checkpoints"][0]["workers"] == 1
     if len(os.sched_getaffinity(0)) == 1:
-        pytest.skip("one core: the save never needs a pool")
+        pytest.skip("one core: the pool has one thread")
     many = {f"w{i}": jnp.full((100,), float(i), jnp.float32) for i in range(8)}
     ck = BlobCheckpointer(make_session(), many, page_size=4096)
     rec = ck.save(0, many)
